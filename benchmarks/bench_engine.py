@@ -8,14 +8,15 @@ with uniform and Zipf-skewed key distributions, through the donated-buffer
   segment-compacted round schedule; a ``masked`` baseline row (the
   O(exact_rounds x B) reference schedule) is recorded alongside so the JSON
   shows the compaction win directly.
-* ``sharded`` — ``ShardedFeatureEngine.run_stream`` on an 8-way fake-device
+* ``sharded`` — ``ShardedFeatureEngine.run_stream`` on a ``data`` mesh over
+  every accelerator device, or, on the CPU backend, an 8-way fake-device
   mesh (subprocess, so the forced device count never leaks into the caller's
-  jax).  On this CPU-only container the 8 "devices" share the same cores, so
-  the number records dispatch overhead, not scale-out speedup.
+  jax).  The 8 CPU "devices" share the same cores, so a CPU number records
+  dispatch overhead, not scale-out speedup.
 * ``skew``    — the ``layout="block"`` vs ``layout="virtual"`` pair
   (distributed/rebalance.py) over the Table 2 workload regimes
   (streaming/workload.py), recording each layout's padded-vs-useful block
-  slot fraction and throughput on the same 8-fake-device mesh.
+  slot fraction and throughput on the same mesh.
 * ``persist`` — the *durable* fast path: ``run_stream`` with a write-behind
   ``WriteBehindSink`` (streaming/persistence.py) vs the no-persistence
   baseline, at the paper's write budget (Lambda * h = 0.1).  Records
@@ -33,7 +34,7 @@ with uniform and Zipf-skewed key distributions, through the donated-buffer
 
 Every row also carries a peak-memory watermark column
 (``benchmarks.common.memory_watermark``: device allocator stats where the
-backend reports them, host peak RSS on CPU) so donation/zero-copy
+backend reports them, host peak RSS on the CPU backend only) so donation/zero-copy
 regressions are visible between JSON snapshots.
 
 Results land both on stdout (``emit`` rows) and in ``BENCH_engine.json`` at
@@ -48,7 +49,6 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 import time
 
 if __package__ in (None, ""):
@@ -64,6 +64,7 @@ import numpy as np
 
 from benchmarks.common import emit, memory_watermark
 from repro.core import EngineConfig
+from repro.features.engine import ShardedFeatureEngine
 
 _OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_engine.json")
 
@@ -134,15 +135,20 @@ def _run_engine_suite(rng, n_events, n_keys, batch, exact_rounds):
     return rows
 
 
-_SHARDED_CODE = """
-    import jax, numpy as np, json, time
-    from repro.core import EngineConfig
-    from repro.features.engine import ShardedFeatureEngine
-    from benchmarks.bench_engine import _make_stream
-    from benchmarks.common import memory_watermark
+def _data_mesh():
+    """A ``data`` mesh over every device this process sees: the real chips
+    on an accelerator, 8 forced host devices in the CPU child."""
+    n_dev = len(jax.devices())
+    return jax.make_mesh((n_dev,), ("data",)), n_dev
 
-    n_events, n_keys, batch, exact_rounds, seed = {args}
-    mesh = jax.make_mesh((8,), ("data",))
+
+def _mesh_label(n_dev: int) -> str:
+    return f"{n_dev}x{jax.devices()[0].platform}"
+
+
+def _sharded_rows(n_events, n_keys, batch, exact_rounds, seed):
+    """Sharded ``run_stream`` throughput rows over a ``data`` mesh."""
+    mesh, n_dev = _data_mesh()
     rng = np.random.default_rng(seed)
     rows = []
     for skew_name, skew in (("uniform", 0.0), ("zipf", 1.2)):
@@ -155,7 +161,7 @@ _SHARDED_CODE = """
 
             def once():
                 st, _ = eng.run_stream(eng.init_state(), keys, qs, ts,
-                                       batch_per_shard=batch // 8,
+                                       batch_per_shard=batch // n_dev,
                                        rng=jax.random.PRNGKey(0),
                                        collect_info=False)
                 jax.block_until_ready(st.agg)
@@ -166,25 +172,19 @@ _SHARDED_CODE = """
                 t0 = time.perf_counter()
                 once()
                 best = min(best, time.perf_counter() - t0)
-            row = {{"mode": mode, "policy": "pp", "skew": skew_name,
-                    "batch": batch, "n_events": n_events,
-                    "mesh": "8xcpu",
-                    "events_per_s": round(n_events / best, 1)}}
+            row = {"mode": mode, "policy": "pp", "skew": skew_name,
+                   "batch": batch, "n_events": n_events,
+                   "mesh": _mesh_label(n_dev),
+                   "events_per_s": round(n_events / best, 1)}
             row.update(memory_watermark())
             rows.append(row)
-    print("ROWS", json.dumps(rows))
-"""
+    return rows
 
 
-_SKEW_CODE = """
-    import jax, numpy as np, json, time
-    from repro.core import EngineConfig
-    from repro.features.engine import ShardedFeatureEngine
+def _skew_rows(regimes, n_events, batch, seed):
+    """block-vs-virtual layout rows over the Table 2 regimes."""
     from repro.streaming.workload import generate_regime
-    from benchmarks.common import memory_watermark
-
-    regimes, n_events, batch, seed = {args}
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh, n_dev = _data_mesh()
     rows = []
     for regime in regimes:
         stream = generate_regime(regime, seed=seed, n_events=n_events)
@@ -195,12 +195,12 @@ _SKEW_CODE = """
                              budget=0.05, policy="pp"),
                 stream.spec.n_keys, mesh=mesh, mode="fast", layout=layout,
                 key_weights=weights if layout == "virtual" else None)
-            stats = eng.stream_layout_stats(stream.key, batch // 8)
+            stats = eng.stream_layout_stats(stream.key, batch // n_dev)
 
             def once():
                 st, _ = eng.run_stream(eng.init_state(), stream.key,
                                        stream.q, stream.t,
-                                       batch_per_shard=batch // 8,
+                                       batch_per_shard=batch // n_dev,
                                        rng=jax.random.PRNGKey(0),
                                        collect_info=False)
                 jax.block_until_ready(st.agg)
@@ -211,17 +211,16 @@ _SKEW_CODE = """
                 t0 = time.perf_counter()
                 once()
                 best = min(best, time.perf_counter() - t0)
-            row = {{"suite": "skew", "regime": regime, "layout": layout,
-                    "mode": "fast", "batch": batch, "n_events": n_events,
-                    "mesh": "8xcpu", "n_blocks": stats["n_blocks"],
-                    "padded_fraction": round(stats["padded_fraction"], 4),
-                    "useful_fraction":
-                        round(1.0 - stats["padded_fraction"], 4),
-                    "events_per_s": round(n_events / best, 1)}}
+            row = {"suite": "skew", "regime": regime, "layout": layout,
+                   "mode": "fast", "batch": batch, "n_events": n_events,
+                   "mesh": _mesh_label(n_dev), "n_blocks": stats["n_blocks"],
+                   "padded_fraction": round(stats["padded_fraction"], 4),
+                   "useful_fraction":
+                       round(1.0 - stats["padded_fraction"], 4),
+                   "events_per_s": round(n_events / best, 1)}
             row.update(memory_watermark())
             rows.append(row)
-    print("ROWS", json.dumps(rows))
-"""
+    return rows
 
 
 def _run_persist_suite(n_events, n_keys, batch, seed):
@@ -854,41 +853,45 @@ def _run_residency_suite(n_events, n_keys, batch, seed):
     return rows
 
 
-def _run_mesh_subprocess(code_tmpl: str, args, table: str):
-    """Run a suite body on 8 fake devices (subprocess, so the forced device
-    count never leaks into the caller's jax) and emit its rows."""
-    env = {"PYTHONPATH": "src:" + os.path.dirname(os.path.dirname(
-               os.path.abspath(__file__))),
-           "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "JAX_PLATFORMS": "cpu"}
-    code = textwrap.dedent(code_tmpl.format(args=args))
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-    if r.returncode != 0:
-        print(f"{table} suite failed:", r.stderr[-2000:])
-        return []
-    rows = json.loads(r.stdout.split("ROWS", 1)[1])
+def _run_mesh_suite(rows_fn, args, table: str):
+    """Run a mesh suite and emit its rows.  On an accelerator it runs in
+    this process over the real devices.  On the CPU it runs in a child
+    with 8 forced host devices, so the forced device count never leaks into
+    the caller's jax; a failed child raises."""
+    if jax.default_backend() != "cpu":
+        rows = rows_fn(*args)
+    else:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {"PYTHONPATH": "src:" + root,
+               "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+               "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+               "JAX_PLATFORMS": "cpu"}
+        code = (f"import json\n"
+                f"from benchmarks.bench_engine import {rows_fn.__name__}\n"
+                f"print('ROWS', json.dumps({rows_fn.__name__}(*{args!r})))")
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=env, cwd=root)
+        if r.returncode != 0:
+            raise RuntimeError(f"{table} suite failed:\n{r.stderr[-2000:]}")
+        rows = json.loads(r.stdout.split("ROWS", 1)[1])
     for row in rows:
         emit(table, row)
     return rows
 
 
 def _run_sharded_suite(n_events, n_keys, batch, exact_rounds, seed):
-    """Sharded run_stream throughput on 8 fake devices (subprocess)."""
-    return _run_mesh_subprocess(
-        _SHARDED_CODE, (n_events, n_keys, batch, exact_rounds, seed),
+    """Sharded run_stream throughput over a ``data`` mesh."""
+    return _run_mesh_suite(
+        _sharded_rows, (n_events, n_keys, batch, exact_rounds, seed),
         "engine_sharded")
 
 
 def _run_skew_suite(n_events, batch, seed,
                     regimes=("fraud", "ibm", "iiot", "wikipedia")):
     """block-vs-virtual layout padding + throughput over the Table 2 Zipf
-    regimes (8 fake devices, subprocess)."""
-    return _run_mesh_subprocess(
-        _SKEW_CODE, (tuple(regimes), n_events, batch, seed), "engine_skew")
+    regimes."""
+    return _run_mesh_suite(
+        _skew_rows, (tuple(regimes), n_events, batch, seed), "engine_skew")
 
 
 def _suite_of_row(row: dict) -> str:
@@ -902,19 +905,13 @@ def write_rows(rows, suites) -> None:
     """Merge ``rows`` into BENCH_engine.json, keeping every row whose
     suite was NOT run this invocation — a partial run never clobbers the
     other suites' trajectories.  Shared with ``bench_serving``."""
-    try:
-        kept = []
-        if os.path.exists(_OUT_PATH):
-            try:
-                with open(_OUT_PATH) as f:
-                    old = json.load(f).get("rows", [])
-                kept = [r for r in old if _suite_of_row(r) not in suites]
-            except (ValueError, OSError):
-                kept = []
-        with open(_OUT_PATH, "w") as f:
-            json.dump({"bench": "engine", "rows": kept + rows}, f, indent=1)
-    except OSError:
-        pass
+    kept = []
+    if os.path.exists(_OUT_PATH):
+        with open(_OUT_PATH) as f:
+            old = json.load(f).get("rows", [])
+        kept = [r for r in old if _suite_of_row(r) not in suites]
+    with open(_OUT_PATH, "w") as f:
+        json.dump({"bench": "engine", "rows": kept + rows}, f, indent=1)
 
 
 def run(n_events: int = 65_536, n_keys: int = 4_096, batch: int = 4_096,
@@ -949,7 +946,7 @@ if __name__ == "__main__":
                     choices=("engine", "sharded", "skew", "persist",
                              "residency", "serving", "all"),
                     help="engine: local throughput (+ masked-vs-compact "
-                         "exact rows); sharded: 8-fake-device run_stream; "
+                         "exact rows); sharded: data-mesh run_stream; "
                          "skew: block-vs-virtual layout padding over the "
                          "Table 2 regimes; persist: write-behind durable "
                          "fast path vs no-persistence baseline; residency: "
@@ -965,4 +962,6 @@ if __name__ == "__main__":
               "serving") \
         if args.suite == "all" else (args.suite,)
     n_events = min(args.n_events, 8_192) if args.smoke else args.n_events
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(n_events=n_events, suites=suites, write_json=not args.smoke)

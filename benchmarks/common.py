@@ -28,8 +28,9 @@ def memory_watermark() -> dict:
     copy; a zero-copy regression shows up as a watermark jump between
     successive BENCH_engine.json snapshots.  Backends that report allocator
     stats (TPU/GPU) give ``peak_bytes_in_use`` per device; the CPU backend
-    reports none, so we fall back to the host's peak RSS (which still moves
-    when donation breaks, since XLA:CPU buffers live in host memory).
+    reports none, so there we fall back to the host's peak RSS (which still
+    moves when donation breaks, since XLA:CPU buffers live in host memory).
+    Any other backend that reports no peak is an error, never host RSS.
 
     Semantics: both sources are **process-lifetime cumulative peaks** — they
     never reset, so within one JSON snapshot later rows inherit earlier
@@ -38,14 +39,14 @@ def memory_watermark() -> dict:
     need one subprocess per row; the cross-snapshot trajectory is what the
     regression check needs.
     """
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
-        if peak:
-            return {"mem_watermark_bytes": int(peak),
-                    "mem_watermark_src": "device"}
-    except Exception:
-        pass
+    dev = jax.local_devices()[0]
+    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    if peak:
+        return {"mem_watermark_bytes": int(peak),
+                "mem_watermark_src": "device"}
+    if dev.platform != "cpu":
+        raise RuntimeError(f"{dev.platform} device {dev.device_kind} reports "
+                           f"no peak_bytes_in_use")
     import resource
     rss_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
     return {"mem_watermark_bytes": int(rss_kb) * 1024,

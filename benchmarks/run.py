@@ -43,6 +43,8 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     names = list(SUITES) if not args.only else args.only.split(",")
     failures = []

@@ -342,8 +342,13 @@ def make_step(cfg: EngineConfig, mode: str = "exact", *,
 
 
 def materialize_features(state: ProfileState, keys: jax.Array, t: jax.Array,
-                         taus) -> jax.Array:
-    """Read-only feature materialization (serving path)."""
+                         taus, out_sharding=None) -> jax.Array:
+    """Read-only feature materialization (serving path).
+
+    ``out_sharding``: sharding of the gathered rows, required when the state
+    is sharded over a mesh with explicit axes (the sharded engine passes
+    replicated)."""
     taus = jnp.asarray(taus, jnp.float32)
-    agg_now = estimators.decay_to(state.agg[keys], state.last_t[keys], t, taus)
+    rows = lambda x: x.at[keys].get(out_sharding=out_sharding)
+    agg_now = estimators.decay_to(rows(state.agg), rows(state.last_t), t, taus)
     return estimators.materialize(agg_now)

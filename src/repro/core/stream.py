@@ -87,7 +87,7 @@ from repro.core.engine import make_step
 from repro.core.types import EngineConfig, Event, ProfileState, StepInfo
 
 __all__ = ["run_stream", "block_runner_for", "sink_step_for",
-           "residency_step_for", "hydrate_scatter"]
+           "residency_step_for", "gather_rows", "hydrate_scatter"]
 
 
 def block_runner_for(step, collect_info: bool = True, donate: bool = True):
@@ -122,7 +122,18 @@ def block_runner_for(step, collect_info: bool = True, donate: bool = True):
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 
 
-def sink_step_for(step, collect_info: bool = True, donate: bool = True):
+def gather_rows(state: ProfileState, idx):
+    """Gather the sink's post-update rows: ``(scalars[4, N], agg[N, T, 3])``
+    at the flat state rows ``idx`` (any shape, flattened), scalar columns
+    stacked ``[last_t, v_f, v_full, last_t_full]``."""
+    idx = jnp.reshape(idx, (-1,))
+    scal = jnp.stack([state.last_t[idx], state.v_f[idx], state.v_full[idx],
+                      state.last_t_full[idx]])
+    return scal, state.agg[idx]
+
+
+def sink_step_for(step, collect_info: bool = True, donate: bool = True,
+                  gather=None):
     """Per-group jitted step for the write-behind persistence path.
 
     Unlike ``block_runner_for`` (one scan over all blocks), the sink path
@@ -138,8 +149,9 @@ def sink_step_for(step, collect_info: bool = True, donate: bool = True):
     The returned callable is ``(state, events[G, B], rng,
     gather_idx[G*B], *consts) -> (state, outs, (scalars[4, G*B],
     agg[G*B, T, 3]))`` where the rows are the *post-update* profile rows
-    gathered at ``gather_idx`` (flat state row per lane; the local engine
-    passes the group's keys, the sharded engine its layout's flat rows) —
+    gathered at ``gather_idx`` (state row per lane; the local engine
+    passes the group's keys, the sharded engine each lane's row within
+    its own shard) —
     scalar columns stacked as ``[last_t, v_f, v_full, last_t_full]`` so
     the host pays two device reads per group, not five.  Rows are
     end-of-group snapshots; since persisted columns only change on a
@@ -154,16 +166,20 @@ def sink_step_for(step, collect_info: bool = True, donate: bool = True):
     ``(z, writes)`` pair the sink actually needs, so XLA dead-code-
     eliminates the per-event p/lam/features materialization exactly like
     the scan path does.
+
+    ``gather`` overrides ``gather_rows``: the sharded engine passes a
+    ``shard_map``-wrapped one that gathers each shard's lanes from its own
+    rows, returning ``(scalars[4, G, W], agg[G, W, T, 3])``; the sink
+    flattens either form on the host.
     """
+    gather = gather or gather_rows
+
     def run(state: ProfileState, events: Event, rng, gather_idx, *consts):
         def body(st, ev):
             st, info = step(st, ev, rng, *consts)
             return st, (info if collect_info else (info.z, info.writes))
         state, outs = jax.lax.scan(body, state, events)
-        scal = jnp.stack([state.last_t[gather_idx], state.v_f[gather_idx],
-                          state.v_full[gather_idx],
-                          state.last_t_full[gather_idx]])
-        return state, outs, (scal, state.agg[gather_idx])
+        return state, outs, gather(state, gather_idx)
 
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 
@@ -189,7 +205,7 @@ def hydrate_scatter(state: ProfileState, slots, scal, agg) -> ProfileState:
 
 
 def residency_step_for(step, collect_info: bool = True, donate: bool = True,
-                       scatter=None):
+                       scatter=None, gather=None):
     """``sink_step_for`` plus a hydration prologue for bounded residency.
 
     The returned callable is ``(state, events, rng, gather_idx,
@@ -202,11 +218,12 @@ def residency_step_for(step, collect_info: bool = True, donate: bool = True,
     ``(Event, rng_entity)`` so thinning stays keyed on global entity ids
     and decisions are residency-invariant.  ``scatter`` overrides the
     hydration scatter (the sharded engine passes a ``shard_map``-wrapped
-    one); ``H`` is padded to a power of two by the drivers so the jit
-    cache stays small.  The donation contract of ``sink_step_for``
-    applies unchanged.
+    one) and ``gather`` the row gather, as in ``sink_step_for``; ``H`` is
+    padded to a power of two by the drivers so the jit cache stays small.
+    The donation contract of ``sink_step_for`` applies unchanged.
     """
     scatter = scatter or hydrate_scatter
+    gather = gather or gather_rows
 
     def run(state: ProfileState, events, rng, gather_idx, h_slots, h_scal,
             h_agg, *consts):
@@ -216,10 +233,7 @@ def residency_step_for(step, collect_info: bool = True, donate: bool = True,
             st, info = step(st, ev, rng, *consts)
             return st, (info if collect_info else (info.z, info.writes))
         state, outs = jax.lax.scan(body, state, events)
-        scal = jnp.stack([state.last_t[gather_idx], state.v_f[gather_idx],
-                          state.v_full[gather_idx],
-                          state.last_t_full[gather_idx]])
-        return state, outs, (scal, state.agg[gather_idx])
+        return state, outs, gather(state, gather_idx)
 
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 

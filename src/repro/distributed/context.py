@@ -12,7 +12,7 @@ import threading
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 _STATE = threading.local()
 
@@ -36,16 +36,26 @@ def get_rules() -> Optional[dict]:
     return _get().rules
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type Auto.
+
+    Model code places activations with ``with_sharding_constraint`` and
+    leaves the rest to sharding propagation; on ``jax.make_mesh``'s default
+    Explicit axes those constraints become type assertions and gathers of
+    sharded tables need spelled-out output shardings."""
+    if mesh.are_all_axes_auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @contextlib.contextmanager
 def mesh_context(mesh: Mesh, rules: dict):
     prev = (_get().mesh, _get().rules)
+    mesh = auto_axes(mesh)
     set_mesh(mesh, rules)
     try:
-        # jax >= 0.5 spells the global-mesh scope jax.sharding.use_mesh /
-        # set_mesh; on 0.4.x the Mesh object is itself the context manager.
-        scope = getattr(jax.sharding, "use_mesh", None) \
-            or getattr(jax.sharding, "set_mesh", None)
-        with (scope(mesh) if scope is not None else mesh):
+        with jax.set_mesh(mesh):
             yield
     finally:
         set_mesh(*prev)

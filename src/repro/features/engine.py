@@ -3,7 +3,7 @@
 The paper's partitioned workers (§5.3) map to SPMD shards: each shard of
 the ``data`` mesh axes owns a subset of entities and runs the vectorized
 core engine over its own event partition inside a
-``jax.experimental.shard_map`` — deterministic key routing, per-key ordering
+``jax.shard_map`` — deterministic key routing, per-key ordering
 within a shard, no cross-shard collectives on the decision or update path
 (the paper's no-coordination design goal, realized in mesh form).  Every
 shard routes its decision + read-modify-write through the same fused
@@ -59,7 +59,6 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import EngineConfig, Event, ProfileState, StepInfo
@@ -352,7 +351,7 @@ class ShardedFeatureEngine:
 
         def sharded(state, ev_ent, rng):
             ev, ent = ev_ent
-            st2, info = shard_map(
+            st2, info = jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(jax.tree.map(lambda _: P(axes), state),
@@ -361,12 +360,53 @@ class ShardedFeatureEngine:
                 out_specs=(jax.tree.map(lambda _: P(axes), state),
                            StepInfo(z=P(axes), p=P(axes), lam_hat=P(axes),
                                     features=P(axes), writes=P(axes))),
-                check_rep=False,
+                check_vma=False,
             )(state, (ev, ent), rng)
             return st2, info._replace(writes=info.writes.sum())
 
         self._step_res = sharded
         return self._step_res
+
+    def _stream_order(self, info: StepInfo, slot: np.ndarray) -> StepInfo:
+        """Per-block ``[n_blocks, W]`` outputs back to flat stream order
+        (``slot``: each event's flat block-major slot)."""
+        if self.mesh is not None:
+            # the outputs are sharded over the block columns; replicate
+            # them so the flat reshape and the slot gather stay local
+            info = jax.device_put(info, NamedSharding(self.mesh, P()))
+        flat = lambda x: jnp.reshape(x, (-1,) + x.shape[2:])[slot]
+        return StepInfo(
+            z=flat(info.z), p=flat(info.p), lam_hat=flat(info.lam_hat),
+            features=flat(info.features),
+            writes=jnp.sum(info.writes).astype(jnp.int32))
+
+    def _shard_gather(self):
+        """Row gather for the sink drivers: each shard gathers its block
+        columns' rows from its own state (``gather_idx`` holds per-shard
+        local rows, ``[G, W]``), so the gather needs no collective.
+        ``None`` selects the core gather when there is no mesh (local
+        rows are then the flat rows)."""
+        if self.mesh is None:
+            return None
+        axes = self.data_axes
+
+        def local(st, idx):
+            scal, agg = core_stream.gather_rows(st, idx)
+            G = idx.shape[0]
+            return (scal.reshape(4, G, -1),
+                    agg.reshape((G, -1) + agg.shape[1:]))
+
+        def gather(state, idx):
+            return jax.shard_map(
+                local,
+                mesh=self.mesh,
+                in_specs=(jax.tree.map(lambda _: P(axes), state),
+                          P(None, axes)),
+                out_specs=(P(None, None, axes), P(None, axes)),
+                check_vma=False,
+            )(state, idx)
+
+        return gather
 
     def _residency_scatter(self):
         """Hydration scatter for ``residency_step_for``: per shard, local
@@ -377,13 +417,13 @@ class ShardedFeatureEngine:
         axes = self.data_axes
 
         def scat(state, slots, scal, agg):
-            return shard_map(
+            return jax.shard_map(
                 core_stream.hydrate_scatter,
                 mesh=self.mesh,
                 in_specs=(jax.tree.map(lambda _: P(axes), state),
                           P(axes), P(None, axes), P(axes)),
                 out_specs=jax.tree.map(lambda _: P(axes), state),
-                check_rep=False,
+                check_vma=False,
             )(state, slots, scal, agg)
 
         return scat
@@ -419,7 +459,7 @@ class ShardedFeatureEngine:
         const_specs = (P(axes),) if virtual else ()
 
         def sharded(state, ev, rng, *consts):
-            st2, info = shard_map(
+            st2, info = jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(jax.tree.map(lambda _: P(axes), state),
@@ -428,7 +468,7 @@ class ShardedFeatureEngine:
                 out_specs=(jax.tree.map(lambda _: P(axes), state),
                            StepInfo(z=P(axes), p=P(axes), lam_hat=P(axes),
                                     features=P(axes), writes=P(axes))),
-                check_rep=False,
+                check_vma=False,
             )(state, ev, rng, *consts)
             return st2, info._replace(writes=info.writes.sum())
 
@@ -506,11 +546,7 @@ class ShardedFeatureEngine:
                                          *self._step_consts)
         if not collect_info:
             return state, info
-        flat = lambda x: jnp.reshape(x, (-1,) + x.shape[2:])[slot]
-        return state, StepInfo(
-            z=flat(info.z), p=flat(info.p), lam_hat=flat(info.lam_hat),
-            features=flat(info.features),
-            writes=jnp.sum(info.writes).astype(jnp.int32))
+        return state, self._stream_order(info, slot)
 
     def _run_stream_sink(self, state, keys, qs, ts, batch_per_shard, rng,
                          collect_info, donate, sink, sink_group,
@@ -518,8 +554,8 @@ class ShardedFeatureEngine:
         """Write-behind block loop for the sharded path.
 
         Reuses ``core.stream._drive_with_sink``; the per-lane gather index
-        is the layout's flat state row (``shard * E_local + local``,
-        reconstructed on device from the block column), and the sink keys
+        is the lane's row within its own shard (``_shard_gather``), and the
+        sink keys
         are *global* entity ids (arithmetic under the block layout, via the
         ``gid_of_row`` table under the virtual layout) so stored rows are
         keyed exactly like the per-event worker's.
@@ -532,11 +568,10 @@ class ShardedFeatureEngine:
         out_key, out_q, out_t, out_valid, slot, n_blocks = \
             route_stream_blocks(shard, local, q, t, n, B)
         W = n * B
-        E_local = self.entities_per_shard
         shard_of_col = np.repeat(np.arange(n, dtype=np.int64), B)
-        flat_host = shard_of_col[None, :] * E_local \
-            + out_key.reshape(n_blocks, W)
         if self.layout == "virtual":
+            flat_host = shard_of_col[None, :] * self.entities_per_shard \
+                + out_key.reshape(n_blocks, W)
             gid_host = np.asarray(self.vlayout.gid_of_row)[flat_host]
         else:
             gid_host = out_key.reshape(n_blocks, W).astype(np.int64) * n \
@@ -554,12 +589,13 @@ class ShardedFeatureEngine:
         def group_of(lo, hi):
             ev = Event(key=put(kb[lo:hi]), q=put(qb[lo:hi]),
                        t=put(tb[lo:hi]), valid=put(vb[lo:hi]))
-            return ev, flat_host[lo:hi].reshape(-1)
+            return ev, kb[lo:hi]
 
         rkey = ("sink", collect_info, donate)
         if rkey not in self._runners:
             self._runners[rkey] = core_stream.sink_step_for(
-                self._raw_step(), collect_info, donate)
+                self._raw_step(), collect_info, donate,
+                gather=self._shard_gather())
         state, info = core_stream._drive_with_sink(
             self._runners[rkey], state, n_blocks, max(1, int(sink_group)),
             group_of, rng, sink, sink_keys=gid_host, valid_host=vb,
@@ -567,11 +603,7 @@ class ShardedFeatureEngine:
             pipeline_depth=pipeline_depth)
         if not collect_info:
             return state, info
-        flat = lambda x: jnp.reshape(x, (-1,) + x.shape[2:])[slot]
-        return state, StepInfo(
-            z=flat(info.z), p=flat(info.p), lam_hat=flat(info.lam_hat),
-            features=flat(info.features),
-            writes=jnp.sum(info.writes).astype(jnp.int32))
+        return state, self._stream_order(info, slot)
 
     def _run_stream_residency(self, state, keys, qs, ts, batch_per_shard,
                               rng, collect_info, donate, sink, sink_group,
@@ -623,7 +655,6 @@ class ShardedFeatureEngine:
         qb = out_q.reshape(n_blocks, W)
         tb = out_t.reshape(n_blocks, W)
         vb = out_valid.reshape(n_blocks, W)
-        shard_of_col = np.repeat(np.arange(n, dtype=np.int64), B)
         if self.mesh is not None:
             sh = NamedSharding(self.mesh, P(None, self.data_axes))
             put = lambda x: jax.device_put(jnp.asarray(x), sh)
@@ -683,8 +714,6 @@ class ShardedFeatureEngine:
                 # rng entity ids: the raw key blocks (padding lanes are 0
                 # from the packer; the engine masks invalid lanes itself)
                 ent = put(kseg)
-                gather_idx = (shard_of_col[None, :] * S + slots
-                              ).reshape(-1)
 
                 def build(rows_fresh, rows_re, miss=miss, H=H):
                     # shared iterators: merge_miss_rows consumes each
@@ -701,7 +730,7 @@ class ShardedFeatureEngine:
                             np.concatenate([g[2] for g in segs], axis=0))
 
                 plans.append(core_stream._GroupPlan(
-                    (ev, ent), gather_idx, kseg.reshape(-1),
+                    (ev, ent), slots, kseg.reshape(-1),
                     vm.reshape(-1), fresh_keys, re_keys, build,
                     last=j == n_sub - 1))
             return plans
@@ -710,18 +739,15 @@ class ShardedFeatureEngine:
         if rkey not in self._runners:
             self._runners[rkey] = core_stream.residency_step_for(
                 self._residency_step(), collect_info, donate,
-                scatter=self._residency_scatter())
+                scatter=self._residency_scatter(),
+                gather=self._shard_gather())
         state, info = core_stream._drive_with_residency(
             self._runners[rkey], state, n_blocks, max(1, int(sink_group)),
             plan_group, rng, sink, collect_info=collect_info,
             pipeline_depth=pipeline_depth)
         if not collect_info:
             return state, info
-        flat = lambda x: jnp.reshape(x, (-1,) + x.shape[2:])[slot_map]
-        return state, StepInfo(
-            z=flat(info.z), p=flat(info.p), lam_hat=flat(info.lam_hat),
-            features=flat(info.features),
-            writes=jnp.sum(info.writes).astype(jnp.int32))
+        return state, self._stream_order(info, slot_map)
 
     # ------------------------------------------------------- persistence
     def make_sink(self, **kw) -> "persistence.WriteBehindSink":
@@ -800,8 +826,10 @@ class ShardedFeatureEngine:
         else:
             flat = (keys % self.n_shards) * self.entities_per_shard \
                 + keys // self.n_shards
+        out = None if self.mesh is None else NamedSharding(self.mesh, P())
         return core_engine.materialize_features(state, flat, t,
-                                                self.cfg.taus)
+                                                self.cfg.taus,
+                                                out_sharding=out)
 
     def materialize_cold(self, stores, keys, t, l2_probe=None) -> jax.Array:
         """Score straight from durable bytes — restart as cold-start

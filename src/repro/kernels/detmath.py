@@ -11,8 +11,7 @@ fused decision+update math to produce bit-identical float32 results in
 *every* compilation context it is traced into: the block driver's
 ``lax.scan`` body, the sink path's per-block jit, and a per-event B=1 call.
 
-Two XLA CPU behaviours break that assumption (measured on this container,
-jax 0.4.37):
+Two behaviours of XLA's CPU backend break that assumption:
 
 * ``jnp.exp`` lowers to either a scalar libm call or a vectorized
   polynomial depending on the surrounding program — 1 ulp apart on

@@ -16,7 +16,10 @@ column), 'fixed' (constant rate) and 'unfiltered' (p = 1).
 
 Layout: rows (events) on the sublane axis, the 3T aggregate columns +
 control scalars on the lane axis.  All math is elementwise/broadcast over
-an (block_b, 3T) tile, so the kernel is a single fused VPU pipeline.
+an (block_b, 3T) tile, so the kernel is a single fused VPU pipeline.  Inside
+the kernel the aggregate tile is component-major ([c0..cT-1, s0.., q0..]) so
+count, sum and sumsq are contiguous lane slices; ``thinning_rmw_pallas``
+converts from and back to the callers' tau-major layout around the call.
 
 The gather of rows by entity id (and the conflict-free scatter back) remain
 XLA ops around the kernel — see core/engine.py for the batching semantics.
@@ -48,7 +51,7 @@ def _kernel(taus_ref, last_t_ref, v_f_ref, agg_ref, q_ref, t_ref, u_ref,
     valid = valid_ref[...] > 0.5             # [bb, 1]
     v_full = v_full_ref[...]                 # [bb, 1]
     last_t_full = last_t_full_ref[...]       # [bb, 1]
-    agg = agg_ref[...]                       # [bb, T*3]
+    agg = agg_ref[...]                       # [bb, 3T] component-major
 
     fresh = last_t < -1e30                   # sentinel for "never persisted"
     dt = jnp.where(fresh, 0.0, jnp.maximum(t - last_t, 0.0))
@@ -58,12 +61,13 @@ def _kernel(taus_ref, last_t_ref, v_f_ref, agg_ref, q_ref, t_ref, u_ref,
     # the jnp reference across compilation contexts — see ref.py.
     beta_tau = jnp.exp(dt * (-1.0 / taus[None, :]))            # [bb, T]
     beta_tau = jnp.where(fresh, 0.0, beta_tau)
-    beta3 = jnp.repeat(beta_tau, 3, axis=1)                    # [bb, 3T]
+    beta3 = jnp.concatenate([beta_tau] * 3, axis=1)            # [bb, 3T]
     agg_now = agg * beta3
 
-    cnt = agg_now[:, 0::3]                                     # [bb, T]
-    sm = agg_now[:, 1::3]
-    sq = agg_now[:, 2::3]
+    # contiguous lane slices: the TPU compiler refuses strided lane slices
+    cnt = agg_now[:, :n_taus]                                  # [bb, T]
+    sm = agg_now[:, n_taus:2 * n_taus]
+    sq = agg_now[:, 2 * n_taus:]
     mean = sm / jnp.maximum(cnt, 1e-12)
     var = jnp.maximum(sq / jnp.maximum(cnt, 1e-12) - mean * mean, 0.0)
     feat_ref[...] = jnp.concatenate([cnt, sm, mean, jnp.sqrt(var)], axis=1)
@@ -103,10 +107,10 @@ def _kernel(taus_ref, last_t_ref, v_f_ref, agg_ref, q_ref, t_ref, u_ref,
 
     # ---- Horvitz-Thompson masked update (only z rows change)
     inv_p = jnp.where(z, 1.0 / p, 0.0)                         # [bb, 1]
-    w3 = jnp.concatenate([jnp.ones_like(q), q, q * q], axis=1)  # [bb, 3]
-    # tile -> [1 q q2, 1 q q2, ...]: tau-major / entry-minor, matching the
-    # [T*3] flattening of agg.
-    w_cols = jnp.tile(w3, (1, n_taus))                          # [bb, 3T]
+    # [1 .. 1, q .. q, q2 .. q2]: component-major, matching agg's layout
+    w_cols = jnp.concatenate(
+        [jnp.broadcast_to(w, cnt.shape) for w in (jnp.ones_like(q), q, q * q)],
+        axis=1)                                                 # [bb, 3T]
     agg_new = agg_now + inv_p * w_cols
     new_agg_ref[...] = jnp.where(z, agg_new, agg)
     new_v_f_ref[...] = jnp.where(z, inv_p + beta_h * v_f, v_f)
@@ -142,6 +146,8 @@ def thinning_rmw_pallas(taus, last_t, v_f, agg_flat, q, t, u, valid,
     grid = (B // block_b,)
     col = lambda i: (i, 0)
     as_col = lambda x: x[:, None].astype(jnp.float32)
+    # [B, a*b] -> [B, b*a]: tau-major <-> component-major aggregate columns
+    swap = lambda x, a, b: x.reshape(B, a, b).swapaxes(1, 2).reshape(B, -1)
 
     kernel = functools.partial(
         _kernel, h=h, budget=budget, alpha=alpha, policy=policy,
@@ -187,10 +193,11 @@ def thinning_rmw_pallas(taus, last_t, v_f, agg_flat, q, t, u, valid,
         ],
         interpret=interpret,
     )(taus[None, :].astype(jnp.float32), as_col(last_t), as_col(v_f),
-      agg_flat.astype(jnp.float32), as_col(q), as_col(t), as_col(u),
-      as_col(valid), as_col(v_full), as_col(last_t_full))
+      swap(agg_flat.astype(jnp.float32), n_taus, 3), as_col(q), as_col(t),
+      as_col(u), as_col(valid), as_col(v_full), as_col(last_t_full))
     (new_last_t, new_v_f, new_agg, new_v_full, new_last_t_full, z, p, lam,
      feats) = outs
-    return (new_last_t[:, 0], new_v_f[:, 0], new_agg, z[:, 0] > 0.5,
+    return (new_last_t[:, 0], new_v_f[:, 0], swap(new_agg, 3, n_taus),
+            z[:, 0] > 0.5,
             p[:, 0], feats, lam[:, 0], new_v_full[:, 0],
             new_last_t_full[:, 0])

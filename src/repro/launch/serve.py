@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ARCH_IDS, load_config, load_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import backbone
 from repro.serving.engine import make_serve_step, sample_token
 
@@ -176,6 +177,7 @@ def main(argv=None):
     ap.add_argument("--residency", type=int, default=0,
                     help="resident-slot budget (0: dense state)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.frontend == "scoring":
         if args.requests == 8:          # llm-sized default: too small to
             args.requests = 4096        # exercise the batcher

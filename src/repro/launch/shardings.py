@@ -24,8 +24,8 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 
 def _sds(shape, dtype, mesh, pspec):
-    return jax.ShapeDtypeStruct(shape, dtype,
-                                sharding=NamedSharding(mesh, pspec))
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(dctx.auto_axes(mesh), pspec))
 
 
 def _replicated(sds_tree, mesh):

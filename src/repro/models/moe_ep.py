@@ -30,7 +30,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common, ffn
@@ -149,11 +148,11 @@ def moe_ep(p: dict, x: jax.Array, *, num_experts: int, top_k: int,
                 jax.lax.pmean(z, names), jax.lax.pmean(drop, names))
 
     tok_spec = P(data_axes if data_axes else None, "model", None)
-    y, aux, z, drop = shard_map(
+    y, aux, z, drop = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(), P("model"), P("model"), P("model")),
         out_specs=(tok_spec, P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     y = common.shard(y, "batch", "seq", None)

@@ -446,7 +446,9 @@ class WriteBehindSink:
         profile rows gathered per lane — either the driver's stacked form
         ``(scalars[4, B], agg[B, T, 3])`` with scalar columns ordered
         ``[last_t, v_f, v_full, last_t_full]`` (``core.stream.
-        sink_step_for``), or the flat 5-tuple ``(last_t, v_f, agg, v_full,
+        sink_step_for``; the sharded gather's ``[4, G, W]`` /
+        ``[G, W, T, 3]`` lanes flatten to the same order), or the flat
+        5-tuple ``(last_t, v_f, agg, v_full,
         last_t_full)``.  Arguments may be device arrays: the device->host
         conversion happens on the flush thread, overlapping the next
         block's compute.  Blocks (bounded queue) when ``queue_depth``
@@ -1009,13 +1011,16 @@ class WriteBehindSink:
             st.rows_stored += uk.size
             st.dedup_saved += idx.size - uk.size
             if len(rows) == 2:
-                # stacked driver form: (scalars[4, B], agg).  Fetched
-                # whole-block (two fixed-shape host reads) — selecting on
-                # device first would re-trace a gather per distinct
-                # selection size, which costs far more than the copy.
+                # stacked driver form: (scalars[4, B], agg[B, T, 3]), or
+                # [4, G, W] / [G, W, T, 3] from the sharded gather.
+                # Fetched whole-block (two fixed-shape host reads) —
+                # selecting on device first would re-trace a gather per
+                # distinct selection size, which costs far more than the
+                # copy.
                 with self.overlap.device():
-                    scal = np.asarray(rows[0])[:, pick]
-                    agg = np.asarray(rows[1])[pick]
+                    scal = np.asarray(rows[0]).reshape(4, -1)[:, pick]
+                    agg = np.asarray(rows[1])
+                    agg = agg.reshape((-1,) + agg.shape[-2:])[pick]
                 last_t, v_f, v_full, last_t_full = scal
             else:
                 with self.overlap.device():

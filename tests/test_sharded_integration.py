@@ -193,6 +193,10 @@ def test_sharded_run_stream_matches_local_stream():
         perm = (k % 8) * 8 + k // 8
         for a, b, name in zip(st_sh, st_lo, st_sh._fields):
             assert np.array_equal(np.asarray(a)[perm], np.asarray(b)), name
+            # one E/8-row shard of every state column on every device
+            shards = a.addressable_shards
+            assert {s.device for s in shards} == set(mesh.devices.flat), name
+            assert all(s.data.shape[0] == E // 8 for s in shards), name
 
         # cheapest path: per-block write counts only, donated state
         eng2 = ShardedFeatureEngine(cfg, E, mesh=mesh, mode="exact")
