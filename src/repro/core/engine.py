@@ -263,57 +263,66 @@ def _step_fast(cfg: EngineConfig, state: ProfileState, ev: Event, rng,
     # decision outputs (p, z, lam, features) are consumed here — the state
     # fold below is the closed-form segment reduction, which subsumes the
     # kernel's single-event RMW when keys repeat within the batch.
-    u = thinning.uniform_for_events(rng, jnp.where(ev.valid, ent, 0),
-                                    _seq_bits(ev.t))
-    (_, _, _, z, p, feats, lam, _, _) = _fused_rmw(
-        cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
+    with jax.named_scope("decide"):
+        u = thinning.uniform_for_events(rng, jnp.where(ev.valid, ent, 0),
+                                        _seq_bits(ev.t))
+        (_, _, _, z, p, feats, lam, _, _) = _fused_rmw(
+            cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
 
     # --- closed-form segment fold of persisted contributions -------------
     # Final per-key timestamp among persisted events:
-    t_star = jnp.full((num_e + 1,), -jnp.inf).at[
-        jnp.where(z, ev.key, num_e)].max(ev.t)[:num_e]
-    wrote = jnp.isfinite(t_star)
-    t_ref = jnp.where(wrote, t_star, 0.0)
+    with jax.named_scope("fold"):
+        t_star = jnp.full((num_e + 1,), -jnp.inf).at[
+            jnp.where(z, ev.key, num_e)].max(ev.t)[:num_e]
+        wrote = jnp.isfinite(t_star)
+        t_ref = jnp.where(wrote, t_star, 0.0)
 
-    inv_p = jnp.where(z, 1.0 / p, 0.0)
-    # v_f: sum_i (1/p_i) exp(-(t* - t_i)/h) + decay(t* - last_t) * v_f
-    w_v = inv_p * intensity.decay(t_ref[safe_key] - ev.t, cfg.h)
-    v_add = jnp.zeros((num_e + 1,)).at[jnp.where(z, ev.key, num_e)].add(w_v)[:num_e]
-    v_f_new = jnp.where(
-        wrote,
-        v_add + intensity.decay(t_star - state.last_t, cfg.h) * state.v_f,
-        state.v_f)
+        inv_p = jnp.where(z, 1.0 / p, 0.0)
+        # v_f: sum_i (1/p_i) exp(-(t* - t_i)/h) + decay(t* - last_t) * v_f
+        w_v = inv_p * intensity.decay(t_ref[safe_key] - ev.t, cfg.h)
+        v_add = jnp.zeros((num_e + 1,)).at[
+            jnp.where(z, ev.key, num_e)].add(w_v)[:num_e]
+        v_f_new = jnp.where(
+            wrote,
+            v_add + intensity.decay(t_star - state.last_t, cfg.h)
+            * state.v_f,
+            state.v_f)
 
-    # aggregates: same fold per tau/column.
-    beta_ev = intensity.decay((t_ref[safe_key] - ev.t)[:, None], taus)  # [B,T]
-    contrib = (inv_p[:, None, None] * beta_ev[:, :, None] *
-               jnp.stack([jnp.ones_like(ev.q), ev.q, ev.q * ev.q], -1)[:, None, :])
-    agg_add = jnp.zeros((num_e + 1,) + state.agg.shape[1:]).at[
-        jnp.where(z, ev.key, num_e)].add(contrib)[:num_e]
-    agg_new = jnp.where(
-        wrote[:, None, None],
-        agg_add + estimators.decay_to(state.agg, state.last_t, t_star, taus),
-        state.agg)
+        # aggregates: same fold per tau/column.
+        # [B, T]
+        beta_ev = intensity.decay((t_ref[safe_key] - ev.t)[:, None], taus)
+        contrib = (inv_p[:, None, None] * beta_ev[:, :, None] *
+                   jnp.stack([jnp.ones_like(ev.q), ev.q, ev.q * ev.q],
+                             -1)[:, None, :])
+        agg_add = jnp.zeros((num_e + 1,) + state.agg.shape[1:]).at[
+            jnp.where(z, ev.key, num_e)].add(contrib)[:num_e]
+        agg_new = jnp.where(
+            wrote[:, None, None],
+            agg_add + estimators.decay_to(state.agg, state.last_t, t_star,
+                                          taus),
+            state.agg)
 
-    last_t_new = jnp.where(wrote, t_star, state.last_t)
+        last_t_new = jnp.where(wrote, t_star, state.last_t)
 
     # full-stream control column (every valid event).
-    tf_star = jnp.full((num_e + 1,), -jnp.inf).at[
-        jnp.where(ev.valid, ev.key, num_e)].max(ev.t)[:num_e]
-    saw = jnp.isfinite(tf_star)
-    tf_ref = jnp.where(saw, tf_star, 0.0)
-    w_full = jnp.where(ev.valid, 1.0, 0.0) * intensity.decay(
-        tf_ref[safe_key] - ev.t, cfg.h)
-    vfull_add = jnp.zeros((num_e + 1,)).at[
-        jnp.where(ev.valid, ev.key, num_e)].add(w_full)[:num_e]
-    v_full_new = jnp.where(
-        saw,
-        vfull_add + intensity.decay(tf_star - state.last_t_full, cfg.h) * state.v_full,
-        state.v_full)
+    with jax.named_scope("fold_control"):
+        tf_star = jnp.full((num_e + 1,), -jnp.inf).at[
+            jnp.where(ev.valid, ev.key, num_e)].max(ev.t)[:num_e]
+        saw = jnp.isfinite(tf_star)
+        tf_ref = jnp.where(saw, tf_star, 0.0)
+        w_full = jnp.where(ev.valid, 1.0, 0.0) * intensity.decay(
+            tf_ref[safe_key] - ev.t, cfg.h)
+        vfull_add = jnp.zeros((num_e + 1,)).at[
+            jnp.where(ev.valid, ev.key, num_e)].add(w_full)[:num_e]
+        v_full_new = jnp.where(
+            saw,
+            vfull_add + intensity.decay(tf_star - state.last_t_full, cfg.h)
+            * state.v_full,
+            state.v_full)
+        last_t_full_new = jnp.where(saw, tf_star, state.last_t_full)
 
     state = ProfileState(last_t=last_t_new, v_f=v_f_new, agg=agg_new,
-                         v_full=v_full_new,
-                         last_t_full=jnp.where(saw, tf_star, state.last_t_full))
+                         v_full=v_full_new, last_t_full=last_t_full_new)
     info = StepInfo(z=z, p=p, lam_hat=lam, features=feats,
                     writes=jnp.sum(z).astype(jnp.int32))
     return state, info

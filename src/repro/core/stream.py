@@ -84,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import make_step
+from repro.core.spans import span
 from repro.core.types import EngineConfig, Event, ProfileState, StepInfo
 
 __all__ = ["run_stream", "block_runner_for", "sink_step_for",
@@ -113,13 +114,13 @@ def block_runner_for(step, collect_info: bool = True, donate: bool = True):
     memoizes per engine instance, so the runner's lifetime matches its
     engine rather than pinning it globally).
     """
-    def run(state: ProfileState, events: Event, rng, *consts):
+    def scan_blocks(state: ProfileState, events: Event, rng, *consts):
         def body(st, ev):
             st, info = step(st, ev, rng, *consts)
             return st, (info if collect_info else info.writes)
         return jax.lax.scan(body, state, events)
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return jax.jit(scan_blocks, donate_argnums=(0,) if donate else ())
 
 
 def gather_rows(state: ProfileState, idx):
@@ -171,6 +172,9 @@ def sink_step_for(step, collect_info: bool = True, donate: bool = True,
     ``shard_map``-wrapped one that gathers each shard's lanes from its own
     rows, returning ``(scalars[4, G, W], agg[G, W, T, 3])``; the sink
     flattens either form on the host.
+
+    The program compiles as ``jit_run``, the name a device trace gives
+    its executions; its row gather runs under ``named_scope("sink_gather")``.
     """
     gather = gather or gather_rows
 
@@ -179,7 +183,8 @@ def sink_step_for(step, collect_info: bool = True, donate: bool = True,
             st, info = step(st, ev, rng, *consts)
             return st, (info if collect_info else (info.z, info.writes))
         state, outs = jax.lax.scan(body, state, events)
-        return state, outs, gather(state, gather_idx)
+        with jax.named_scope("sink_gather"):
+            return state, outs, gather(state, gather_idx)
 
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 
@@ -225,17 +230,18 @@ def residency_step_for(step, collect_info: bool = True, donate: bool = True,
     scatter = scatter or hydrate_scatter
     gather = gather or gather_rows
 
-    def run(state: ProfileState, events, rng, gather_idx, h_slots, h_scal,
-            h_agg, *consts):
+    def residency_group(state: ProfileState, events, rng, gather_idx,
+                        h_slots, h_scal, h_agg, *consts):
         state = scatter(state, h_slots, h_scal, h_agg)
 
         def body(st, ev):
             st, info = step(st, ev, rng, *consts)
             return st, (info if collect_info else (info.z, info.writes))
         state, outs = jax.lax.scan(body, state, events)
-        return state, outs, gather(state, gather_idx)
+        with jax.named_scope("sink_gather"):
+            return state, outs, gather(state, gather_idx)
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return jax.jit(residency_group, donate_argnums=(0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -568,7 +574,8 @@ def _drive_with_sink(bstep, state, n_blocks, group, group_of, rng, sink, *,
         hi = min(lo + group, n_blocks)
         with sink.overlap.host():
             ev, gidx = group_of(lo, hi)
-        state, outs, rows = bstep(state, ev, rng, gidx, *consts)
+        with span("repro.stream.dispatch", sink.stats, "dispatch_s"):
+            state, outs, rows = bstep(state, ev, rng, gidx, *consts)
         # enqueue device arrays; the flush thread converts + packs + stores
         # (the bounded queue backpressures this loop when storage lags)
         z = outs.z if collect_info else outs[0]
@@ -576,7 +583,7 @@ def _drive_with_sink(bstep, state, n_blocks, group, group_of, rng, sink, *,
                     valid_host[lo:hi].reshape(-1), rows)
         outs_all.append(outs)
 
-    return state, _stack_group_outs(outs_all, collect_info)
+    return state, _stack_group_outs(outs_all, collect_info, sink.stats)
 
 
 def _drive_pipelined_sink(bstep, state, n_blocks, group, group_of, rng,
@@ -633,11 +640,7 @@ def _drive_pipelined_sink(bstep, state, n_blocks, group, group_of, rng,
             # the device window (see the ping-pong contract, module
             # docstring)
             tokens.release()
-            # the jit call occupies the execution engine until the step is
-            # enqueued (on CPU backends that can be the whole computation):
-            # meter it as device-channel time so overlap_frac reflects how
-            # much prep work genuinely hid behind compute
-            with sink.overlap.device():
+            with span("repro.stream.dispatch", sink.stats, "dispatch_s"):
                 state, outs, rows = bstep(state, ev, rng, gidx, *consts)
             z = outs.z if collect_info else outs[0]
             sink.submit(sink_keys[lo:hi].reshape(-1), z,
@@ -646,11 +649,15 @@ def _drive_pipelined_sink(bstep, state, n_blocks, group, group_of, rng,
     finally:
         stop.set()
         th.join()
-    return state, _stack_group_outs(outs_all, collect_info)
+    return state, _stack_group_outs(outs_all, collect_info, sink.stats)
 
 
-def _stack_group_outs(outs_all, collect_info):
-    """Stack per-group outputs back into the scan path's output shape."""
+def _stack_group_outs(outs_all, collect_info, stats):
+    """Stack per-group outputs back into the scan path's output shape.
+
+    Runs on the host (span ``repro.stream.outputs``, ``stats.outputs_s``);
+    ``stats.outputs_d2h_bytes`` counts every output leaf brought off the
+    device, ``z`` included (the sink's wait on it made the one copy)."""
     if not outs_all:                    # empty stream: no groups ran
         if not collect_info:
             return jnp.zeros((0,), jnp.int32)
@@ -659,14 +666,18 @@ def _stack_group_outs(outs_all, collect_info):
                         lam_hat=jnp.zeros((0, 0), jnp.float32),
                         features=jnp.zeros((0, 0, 0), jnp.float32),
                         writes=jnp.zeros((0,), jnp.int32))
-    if not collect_info:
-        return jnp.asarray(np.concatenate(
-            [np.asarray(o[1], np.int32) for o in outs_all]))
-    outs_all = [jax.tree.map(np.asarray, o) for o in outs_all]
-    cat = lambda f: jnp.asarray(np.concatenate(
-        [getattr(o, f) for o in outs_all], axis=0))
-    return StepInfo(z=cat("z"), p=cat("p"), lam_hat=cat("lam_hat"),
-                    features=cat("features"), writes=cat("writes"))
+    stats.outputs_d2h_bytes += sum(
+        int(x.nbytes) for x in jax.tree.leaves(outs_all)
+        if isinstance(x, jax.Array))
+    with span("repro.stream.outputs", stats, "outputs_s"):
+        if not collect_info:
+            return jnp.asarray(np.concatenate(
+                [np.asarray(o[1], np.int32) for o in outs_all]))
+        outs_all = [jax.tree.map(np.asarray, o) for o in outs_all]
+        cat = lambda f: jnp.asarray(np.concatenate(
+            [getattr(o, f) for o in outs_all], axis=0))
+        return StepInfo(z=cat("z"), p=cat("p"), lam_hat=cat("lam_hat"),
+                        features=cat("features"), writes=cat("writes"))
 
 
 def _drive_with_residency(bstep, state, n_blocks, group, plan_group, rng,
@@ -710,7 +721,7 @@ def _drive_with_residency(bstep, state, n_blocks, group, plan_group, rng,
                 sink.submit_read(plan.rehydrate_keys))
 
     if n_blocks == 0:
-        return state, _stack_group_outs([], collect_info)
+        return state, _stack_group_outs([], collect_info, sink.stats)
     # Drain anything a previous run left in flight: the fast lane's
     # safety argument is "this run never wrote a first-touch key", which
     # only covers writes submitted after this point.  A reused sink
@@ -730,8 +741,10 @@ def _drive_with_residency(bstep, state, n_blocks, group, plan_group, rng,
         rows_f, rows_r = t_fresh.result(), t_re.result()
         with sink.overlap.host():
             h_slots, h_scal, h_agg = plan.build_hydration(rows_f, rows_r)
-        state, outs, rows = bstep(state, plan.events, rng, plan.gather_idx,
-                                  h_slots, h_scal, h_agg, *consts)
+        with span("repro.stream.dispatch", sink.stats, "dispatch_s"):
+            state, outs, rows = bstep(state, plan.events, rng,
+                                      plan.gather_idx, h_slots, h_scal,
+                                      h_agg, *consts)
         z = outs.z if collect_info else outs[0]
         sink.submit(plan.sink_keys, z, plan.valid, rows)
         part_outs.append((outs, plan.valid))
@@ -748,7 +761,7 @@ def _drive_with_residency(bstep, state, n_blocks, group, plan_group, rng,
             next_lo = min(next_lo + group, n_blocks)
             i = 0
         t_fresh, t_re = reads_of(pending[i])
-    return state, _stack_group_outs(outs_all, collect_info)
+    return state, _stack_group_outs(outs_all, collect_info, sink.stats)
 
 
 def _drive_pipelined_residency(bstep, state, n_blocks, group, plan_group,
@@ -815,7 +828,7 @@ def _drive_pipelined_residency(bstep, state, n_blocks, group, plan_group,
             "inline flush on the dispatch thread would race the prep "
             "thread's reads on the partition stores")
     if n_blocks == 0:
-        return state, _stack_group_outs([], collect_info)
+        return state, _stack_group_outs([], collect_info, sink.stats)
     sink.flush()   # same fast-lane safety barrier as the serial driver
     ready: queue.Queue = queue.Queue()
     tokens = threading.BoundedSemaphore(depth)
@@ -887,10 +900,7 @@ def _drive_pipelined_residency(bstep, state, n_blocks, group, plan_group,
             # the next group *under* this group's device window instead
             # of after it (ping-pong contract, module docstring)
             tokens.release()
-            # metered as device time: the jit call holds the execution
-            # engine until the step is enqueued (the whole computation on
-            # CPU backends) — the window prep work can hide inside
-            with sink.overlap.device():
+            with span("repro.stream.dispatch", sink.stats, "dispatch_s"):
                 state, outs, rows = bstep(state, plan.events, rng,
                                           plan.gather_idx, h_slots, h_scal,
                                           h_agg, *consts)
@@ -918,7 +928,7 @@ def _drive_pipelined_residency(bstep, state, n_blocks, group, plan_group,
             th.join()
         else:
             th.join()
-    return state, _stack_group_outs(outs_all, collect_info)
+    return state, _stack_group_outs(outs_all, collect_info, sink.stats)
 
 
 def _merge_subgroup_outs(parts, collect_info):

@@ -100,6 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.core.stream import (_block_runner, _residency_step, _sink_step,
                                hydration_width, pack_hydration)
 from repro.core.types import EngineConfig, Event
@@ -207,6 +208,15 @@ class FrontendStats:
     # (advisory: sampled on the driver thread against a cache the flush
     # workers mutate; the read itself probes authoritatively at execution)
     prefetch_l2_hits: int = 0
+    # program spans (``core.spans``): repro.serve.dispatch is a batch's
+    # hydration, jit step and flush submit; repro.serve.materialize waits
+    # for its outputs, copies them to the host and scores them
+    dispatch_s: float = 0.0
+    materialize_s: float = 0.0
+    # clock time from each request's arrival (its due time) to its batch's
+    # dispatch: summed over requests, and the largest
+    queue_wait_s: float = 0.0
+    queue_wait_max_s: float = 0.0
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -512,6 +522,10 @@ class ServingFrontend:
                                                            True)
         t_disp = self.clock.now()
         st = self.stats
+        # FIFO: the batch's first request has waited longest
+        st.queue_wait_s += sum(t_disp - r.arrival_s for r in batch_reqs)
+        st.queue_wait_max_s = max(st.queue_wait_max_s,
+                                  t_disp - batch_reqs[0].arrival_s)
         st.dispatches += 1
         st.events += k
         st.padded_lanes += B - k
@@ -531,36 +545,38 @@ class ServingFrontend:
             pending, full=full, tightened=tightened)
         B = self.batch
         n_miss = n_pre = 0
-        if self._rmap is not None:
-            asn = self._rmap.assign_group(keys, valid)
-            # victims leave the slot plane -> the sink's host L2 tier (if
-            # any): a later prefetch/demand read of them resolves from
-            # host RAM instead of a durable get
-            self.sink.demote(asn.evicted)
-            n_miss = int(asn.miss_keys.size)
-            rows, n_pre = self._hydration_rows(asn, keys[valid])
-            h_slots, h_scal, h_agg = pack_hydration(
-                rows, asn.miss_slots, self.sink.serde, self._rmap.n_slots,
-                self._n_taus, width=self._hwidth)
-            slots = asn.slot.astype(np.int32)
-            sev = Event(key=slots.reshape(1, B), q=ev.q, t=ev.t,
-                        valid=ev.valid)
-            self.state, outs, dev_rows = self._bstep(
-                self.state, (sev, keys[None]), self.rng, slots, h_slots,
-                h_scal, h_agg)
-            self.sink.submit(keys, outs.z, valid, dev_rows)
-        elif self.sink is not None:
-            self.state, outs, dev_rows = self._bstep(self.state, ev,
-                                                     self.rng, keys)
-            self.sink.submit(keys, outs.z, valid, dev_rows)
-        else:
-            self.state, outs = self._bstep(self.state, ev, self.rng)
-        # prefetch the *next* batch's misses now, while this batch's
-        # device compute and flush are still in flight: the ordered read
-        # rides the sink FIFO behind the flush just submitted, so a key
-        # this batch evicted (or updated) reads its latest durable row
-        if self._rmap is not None and pending:
-            self._prefetch_keys([r.key for r in pending])
+        with span("repro.serve.dispatch", self.stats, "dispatch_s"):
+            if self._rmap is not None:
+                asn = self._rmap.assign_group(keys, valid)
+                # victims leave the slot plane -> the sink's host L2 tier
+                # (if any): a later prefetch/demand read of them resolves
+                # from host RAM instead of a durable get
+                self.sink.demote(asn.evicted)
+                n_miss = int(asn.miss_keys.size)
+                rows, n_pre = self._hydration_rows(asn, keys[valid])
+                h_slots, h_scal, h_agg = pack_hydration(
+                    rows, asn.miss_slots, self.sink.serde,
+                    self._rmap.n_slots, self._n_taus, width=self._hwidth)
+                slots = asn.slot.astype(np.int32)
+                sev = Event(key=slots.reshape(1, B), q=ev.q, t=ev.t,
+                            valid=ev.valid)
+                self.state, outs, dev_rows = self._bstep(
+                    self.state, (sev, keys[None]), self.rng, slots, h_slots,
+                    h_scal, h_agg)
+                self.sink.submit(keys, outs.z, valid, dev_rows)
+            elif self.sink is not None:
+                self.state, outs, dev_rows = self._bstep(self.state, ev,
+                                                         self.rng, keys)
+                self.sink.submit(keys, outs.z, valid, dev_rows)
+            else:
+                self.state, outs = self._bstep(self.state, ev, self.rng)
+            # prefetch the *next* batch's misses now, while this batch's
+            # device compute and flush are still in flight: the ordered
+            # read rides the sink FIFO behind the flush just submitted, so
+            # a key this batch evicted (or updated) reads its latest
+            # durable row
+            if self._rmap is not None and pending:
+                self._prefetch_keys([r.key for r in pending])
         self._materialize(out, batch_reqs, k, full, deadline, t_disp, outs,
                           done, n_miss, n_pre)
         return done + k
@@ -658,33 +674,35 @@ class ServingFrontend:
         """Dispatch-thread half of a staged batch: jit step, flush
         submit (trailed by the staged epoch), output materialization."""
         n_miss = n_pre = 0
-        if self._rmap is not None:
-            (sev, keys, valid, slots, h_slots, h_scal, h_agg, seq,
-             n_miss, n_pre) = payload
-            self.state, outs, dev_rows = self._bstep(
-                self.state, (sev, keys[None]), self.rng, slots, h_slots,
-                h_scal, h_agg)
-            self.sink.submit(keys, outs.z, valid, dev_rows, seq=seq)
-        elif self.sink is not None:
-            ev, keys, valid = payload
-            self.state, outs, dev_rows = self._bstep(self.state, ev,
-                                                     self.rng, keys)
-            self.sink.submit(keys, outs.z, valid, dev_rows)
-        else:
-            (ev,) = payload
-            self.state, outs = self._bstep(self.state, ev, self.rng)
+        with span("repro.serve.dispatch", self.stats, "dispatch_s"):
+            if self._rmap is not None:
+                (sev, keys, valid, slots, h_slots, h_scal, h_agg, seq,
+                 n_miss, n_pre) = payload
+                self.state, outs, dev_rows = self._bstep(
+                    self.state, (sev, keys[None]), self.rng, slots, h_slots,
+                    h_scal, h_agg)
+                self.sink.submit(keys, outs.z, valid, dev_rows, seq=seq)
+            elif self.sink is not None:
+                ev, keys, valid = payload
+                self.state, outs, dev_rows = self._bstep(self.state, ev,
+                                                         self.rng, keys)
+                self.sink.submit(keys, outs.z, valid, dev_rows)
+            else:
+                (ev,) = payload
+                self.state, outs = self._bstep(self.state, ev, self.rng)
         self._materialize(out, batch_reqs, k, full, deadline, t_disp, outs,
                           done, n_miss, n_pre)
 
     def _materialize(self, out: ServeResult, batch_reqs, k: int,
                      full: bool, deadline: float, t_disp: float, outs,
                      done: int, n_miss: int, n_pre: int) -> None:
-        feats = np.asarray(outs.features)[0]          # blocks on device
-        z = np.asarray(outs.z)[0]
-        p = np.asarray(outs.p)[0]
-        lam = np.asarray(outs.lam_hat)[0]
-        scores = (score_at_width(self.scorer, feats, self.batch)
-                  if self.scorer is not None else None)
+        with span("repro.serve.materialize", self.stats, "materialize_s"):
+            feats = np.asarray(outs.features)[0]      # blocks on device
+            z = np.asarray(outs.z)[0]
+            p = np.asarray(outs.p)[0]
+            lam = np.asarray(outs.lam_hat)[0]
+            scores = (score_at_width(self.scorer, feats, self.batch)
+                      if self.scorer is not None else None)
         t_done = self.clock.now()
         for lane, r in enumerate(batch_reqs):
             out.z[r.rid] = z[lane]

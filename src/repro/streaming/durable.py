@@ -104,6 +104,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.streaming.kvstore import KVStore, StorageModel
 
 __all__ = ["DurableStore", "DurableCounters", "CorruptionError", "FileOps",
@@ -209,6 +210,8 @@ class DurableCounters:
     bloom_skips: int = 0            # ... answered "absent" with zero I/O
     bloom_false_positives: int = 0  # ... that passed but the key was absent
     # compaction placement (compaction="inline" | "background")
+    compaction_s: float = 0.0       # repro.store.compact: every compaction,
+    #                                 inline or on the compactor thread
     compaction_stall_s: float = 0.0  # inline rewrites riding the flush path
     compact_throttle_s: float = 0.0  # token-bucket sleeps (rate limiter)
     wal_tail_rewrites: int = 0      # background WAL swaps (uncovered tail
@@ -220,7 +223,8 @@ class DurableCounters:
     torn_tails: int = 0
     torn_bytes_dropped: int = 0
     recovery_s: float = 0.0
-    # measured wall time inside write/fsync calls
+    # measured wall time inside write/fsync calls (repro.store.wal_write,
+    # repro.store.fsync; a compaction's file writes are repro.store.seg_write)
     io_write_s: float = 0.0
     io_sync_s: float = 0.0
 
@@ -739,23 +743,20 @@ class DurableStore(KVStore):
             seq = self._next_seq
             buf = _encode_batch(seq, keys, rows)
             pos = self._wal_size
-            t0 = time.perf_counter()
             try:
-                self._wal_f.write(buf)
-                self._wal_f.flush()
+                with span("repro.store.wal_write", d, "io_write_s"):
+                    self._wal_f.write(buf)
+                    self._wal_f.flush()
             except OSError:
-                d.io_write_s += time.perf_counter() - t0
                 try:    # restore the pre-batch length: keep the log clean
                     self._wal_f.truncate(pos)
                     self._wal_f.seek(pos)
                 except OSError:
                     pass   # a kill here leaves a torn tail — recovery drops
                 raise
-            d.io_write_s += time.perf_counter() - t0
             if self.sync:
-                t0 = time.perf_counter()
-                self.fops.fsync(self._wal_f)
-                d.io_sync_s += time.perf_counter() - t0
+                with span("repro.store.fsync", d, "io_sync_s"):
+                    self.fops.fsync(self._wal_f)
                 d.fsyncs += 1
             self._wal_size = pos + len(buf)
             d.wal_bytes += len(buf)
@@ -770,9 +771,9 @@ class DurableStore(KVStore):
             if self._compact_evt is not None:
                 self._compact_evt.set()
             else:
-                t0 = time.perf_counter()
-                self.compact()
-                d.compaction_stall_s += time.perf_counter() - t0
+                with span("repro.store.compact_stall", d,
+                          "compaction_stall_s"):
+                    self.compact()
 
     @staticmethod
     def _as_bytes(rows) -> List[bytes]:
@@ -809,6 +810,10 @@ class DurableStore(KVStore):
             self._compact_impl()
 
     def _compact_impl(self) -> None:
+        with span("repro.store.compact", self.durable, "compaction_s"):
+            self._compact_body()
+
+    def _compact_body(self) -> None:
         d = self.durable
         with self._mtx:
             if self._wal_size == 0:
@@ -846,66 +851,67 @@ class DurableStore(KVStore):
         buf = b"".join(parts)
         seg = self._seg_path(seq0)
         tmp = seg + ".tmp"
-        t0 = time.perf_counter()
         throttled = 0.0
-        with self.fops.open(tmp, "wb") as f:
-            if self._rate is None:
-                f.write(buf)
-            else:
-                for i in range(0, len(buf), _COMPACT_CHUNK):
-                    chunk = buf[i:i + _COMPACT_CHUNK]
-                    throttled += self._rate.throttle(len(chunk))
-                    f.write(chunk)
-            self.fops.fsync(f)
-        d.fsyncs += 1
-        self.fops.replace(tmp, seg)
-        bloom = None
-        if self.bloom_bits_per_key > 0:
-            bloom = _bloom_build(ks, self.bloom_bits_per_key)
-        ibuf = _encode_index(entries, seq0, last_seq, bloom)
-        itmp = self._idx_path(seg) + ".tmp"
-        with self.fops.open(itmp, "wb") as f:
-            f.write(ibuf)
-            self.fops.fsync(f)
-        d.fsyncs += 1
-        self.fops.replace(itmp, self._idx_path(seg))
-        self.fops.fsync_dir(self.path)
-        d.fsyncs += 1
-        # segment durable: the covered WAL prefix is now stale (seq guard)
-        with self._mtx:
-            if self._wal_size == wal_covered:
-                # no appends landed during the build: plain truncate —
-                # byte-identical to the historic inline behavior
-                self._wal_f.truncate(0)
-                self._wal_f.seek(0)
-                self.fops.fsync(self._wal_f)
-                d.fsyncs += 1
-                self._wal_size = 0
-            else:
-                # rewrite the uncovered tail into a fresh log and swap it
-                # in atomically; a crash anywhere in between leaves either
-                # the old WAL (covered prefix goes stale via the seq
-                # guard) or the new one — never a torn log
-                wal = self._wal_path()
-                with self.fops.open(wal, "rb") as f:
-                    f.seek(wal_covered)
-                    tail = f.read()
-                wtmp = wal + ".tmp"
-                with self.fops.open(wtmp, "wb") as f:
-                    f.write(tail)
-                    self.fops.fsync(f)
-                d.fsyncs += 1
-                self.fops.replace(wtmp, wal)
-                old_f = self._wal_f
-                self._wal_f = self.fops.open(wal, "ab")
-                old_f.close()
-                self.fops.fsync_dir(self.path)
-                d.fsyncs += 1
-                self._wal_size = len(tail)
-                d.wal_tail_rewrites += 1
-            self._applied_seq = max(self._applied_seq, last_seq)
-            self._seg_size_bytes = len(buf)
-        d.io_write_s += time.perf_counter() - t0 - throttled
+        # the rate limiter's sleeps are not write time: taken off below
+        with span("repro.store.seg_write", d, "io_write_s"):
+            with self.fops.open(tmp, "wb") as f:
+                if self._rate is None:
+                    f.write(buf)
+                else:
+                    for i in range(0, len(buf), _COMPACT_CHUNK):
+                        chunk = buf[i:i + _COMPACT_CHUNK]
+                        throttled += self._rate.throttle(len(chunk))
+                        f.write(chunk)
+                self.fops.fsync(f)
+            d.fsyncs += 1
+            self.fops.replace(tmp, seg)
+            bloom = None
+            if self.bloom_bits_per_key > 0:
+                bloom = _bloom_build(ks, self.bloom_bits_per_key)
+            ibuf = _encode_index(entries, seq0, last_seq, bloom)
+            itmp = self._idx_path(seg) + ".tmp"
+            with self.fops.open(itmp, "wb") as f:
+                f.write(ibuf)
+                self.fops.fsync(f)
+            d.fsyncs += 1
+            self.fops.replace(itmp, self._idx_path(seg))
+            self.fops.fsync_dir(self.path)
+            d.fsyncs += 1
+            # segment durable: the covered WAL prefix is now stale (seq guard)
+            with self._mtx:
+                if self._wal_size == wal_covered:
+                    # no appends landed during the build: plain truncate —
+                    # byte-identical to the historic inline behavior
+                    self._wal_f.truncate(0)
+                    self._wal_f.seek(0)
+                    self.fops.fsync(self._wal_f)
+                    d.fsyncs += 1
+                    self._wal_size = 0
+                else:
+                    # rewrite the uncovered tail into a fresh log and swap it
+                    # in atomically; a crash anywhere in between leaves either
+                    # the old WAL (covered prefix goes stale via the seq
+                    # guard) or the new one — never a torn log
+                    wal = self._wal_path()
+                    with self.fops.open(wal, "rb") as f:
+                        f.seek(wal_covered)
+                        tail = f.read()
+                    wtmp = wal + ".tmp"
+                    with self.fops.open(wtmp, "wb") as f:
+                        f.write(tail)
+                        self.fops.fsync(f)
+                    d.fsyncs += 1
+                    self.fops.replace(wtmp, wal)
+                    old_f = self._wal_f
+                    self._wal_f = self.fops.open(wal, "ab")
+                    old_f.close()
+                    self.fops.fsync_dir(self.path)
+                    d.fsyncs += 1
+                    self._wal_size = len(tail)
+                    d.wal_tail_rewrites += 1
+                self._applied_seq = max(self._applied_seq, last_seq)
+                self._seg_size_bytes = len(buf)
+        d.io_write_s -= throttled
         d.compact_throttle_s += throttled
         for p in old_segs:
             self.fops.remove(p)

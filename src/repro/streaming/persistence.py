@@ -50,16 +50,17 @@ the exact-mode engine state bit-for-bit.  Two fine points make that exact:
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import queue
 import threading
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.core.types import EngineConfig, ProfileState
 from repro.streaming.durable import BACKENDS, open_partition_stores
 from repro.streaming.kvstore import KVStore, SerDe, StorageModel
@@ -100,15 +101,32 @@ class RetryPolicy:
 
 @dataclasses.dataclass
 class SinkStats:
-    """Host-side sink accounting (store-side counters live on the stores)."""
-    blocks: int = 0
-    events_seen: int = 0        # valid lanes observed
+    """Host-side sink accounting (store-side counters live on the stores).
+
+    The fields commented ``repro.<layer>.<what>`` are program spans
+    (``core.spans``): each is the wall time of one named piece of work,
+    which a profiler trace also shows under that name on the thread that
+    did it."""
+    blocks: int = 0             # flush blocks the dispatcher handled
     selected: int = 0           # lanes whose row is durable this block
     rows_stored: int = 0        # after intra-block last-write-wins dedupe
     dedup_saved: int = 0        # selected - rows_stored
-    serde_s: float = 0.0        # vectorized pack time (dispatcher thread)
-    flush_s: float = 0.0        # total dispatcher busy time
-    submit_wait_s: float = 0.0  # backpressure: time submit() blocked
+    serde_s: float = 0.0        # repro.sink.serde: vectorized row packing
+    flush_s: float = 0.0        # repro.sink.flush: dispatcher busy time
+    # repro.sink.submit: backpressure, the time submit() blocked
+    submit_wait_s: float = 0.0
+    # stream driver (``core.stream``): repro.stream.dispatch is the jitted
+    # flush-group call with its host->device copy of the group's events;
+    # repro.stream.outputs stacks the groups' per-event outputs on the
+    # host, and ``outputs_d2h_bytes`` counts what it brings off the device
+    # (z included: the dispatcher's earlier wait on z is the one copy)
+    dispatch_s: float = 0.0
+    outputs_s: float = 0.0
+    outputs_d2h_bytes: int = 0
+    # repro.sink.rows_d2h: the dispatcher's copy of each block's gathered
+    # post-update rows off the device, and its bytes
+    rows_d2h_s: float = 0.0
+    rows_d2h_bytes: int = 0
     # read path (hydration): submitted reads, rows requested, and the time
     # the driver spent blocked on ticket results
     reads: int = 0
@@ -127,11 +145,12 @@ class SinkStats:
     # demoted into the cache (synced from the caches at ``snapshot``)
     l2_hits: int = 0
     l2_demotions: int = 0
-    # host/device time split (synced from the sink's ``_OverlapMeter`` at
-    # ``snapshot``): ``host_pack_s`` is driver-side group planning+packing
-    # (the drivers wrap it in ``overlap.host()``), ``device_wait_s`` is
-    # time the flush dispatcher spent blocked materializing device arrays
-    # — the sink-gather sync points — and ``overlap_s`` is the wall-clock
+    # host/device time split: ``host_pack_s`` (repro.stream.pack) is
+    # driver-side group planning+packing (the drivers wrap it in
+    # ``overlap.host()``), ``device_wait_s`` (repro.sink.device_wait) is
+    # time the flush dispatcher spent blocked on a group's ``z`` — the
+    # wait for its device compute — and ``overlap_s`` (synced from the
+    # sink's ``_OverlapMeter`` at ``snapshot``) is the wall-clock
     # intersection of the two.  ``overlap_frac = overlap_s/host_pack_s``:
     # the fraction of host pack work that was hidden under device waits.
     host_pack_s: float = 0.0
@@ -161,58 +180,57 @@ class SinkStats:
 class _OverlapMeter:
     """Wall-clock intersection of two activity channels (host, device).
 
-    ``host()`` wraps driver-side group planning/packing; ``device()``
-    wraps the flush dispatcher's device-array materialization waits.  The
-    meter accumulates each channel's total busy time plus the time both
-    were active *simultaneously* — a direct measurement of how much host
-    pack work the pipeline hid under device time, not an inference from
-    wall-clock arithmetic.  Each channel is non-reentrant and owned by
-    one thread at a time (driver/prep thread vs dispatcher thread), which
-    the sink's thread model already guarantees.
+    ``host()`` is the span ``repro.stream.pack`` (``SinkStats.
+    host_pack_s``) around driver-side group planning/packing; ``device()``
+    the span ``repro.sink.device_wait`` (``SinkStats.device_wait_s``)
+    around the flush dispatcher's wait for a group's device result.  Each
+    span hands the meter its enter and exit times, and the meter adds the
+    time both were open *simultaneously* to ``overlap_s`` — a direct
+    measurement of how much host pack work the pipeline hid under device
+    time, not an inference from wall-clock arithmetic.  Each channel is
+    non-reentrant and owned by one thread at a time (driver/prep thread vs
+    dispatcher thread), which the sink's thread model already guarantees.
     """
 
     HOST, DEVICE = 0, 1
 
-    def __init__(self) -> None:
+    def __init__(self, stats: SinkStats) -> None:
+        self._stats = stats
         self._lock = threading.Lock()
         self._since: List[Optional[float]] = [None, None]
         self._both: float = 0.0
-        self.total = [0.0, 0.0]
         self.overlap_s = 0.0
 
-    def begin(self, ch: int) -> None:
-        now = time.perf_counter()
+    def begin(self, ch: int, now: float) -> None:
         with self._lock:
             self._since[ch] = now
             if self._since[1 - ch] is not None:
                 self._both = now
 
-    def end(self, ch: int) -> None:
-        now = time.perf_counter()
+    def end(self, ch: int, now: float) -> None:
         with self._lock:
-            since = self._since[ch]
-            if since is None:  # pragma: no cover - defensive
+            if self._since[ch] is None:  # pragma: no cover - defensive
                 return
-            self.total[ch] += now - since
             self._since[ch] = None
             if self._since[1 - ch] is not None:
                 self.overlap_s += now - self._both
 
-    @contextlib.contextmanager
-    def host(self):
-        self.begin(self.HOST)
-        try:
-            yield
-        finally:
-            self.end(self.HOST)
+    def _edges(self, ch: int):
+        return (lambda t: self.begin(ch, t), lambda t: self.end(ch, t))
 
-    @contextlib.contextmanager
+    def host(self):
+        return span("repro.stream.pack", self._stats, "host_pack_s",
+                    edges=self._edges(self.HOST))
+
     def device(self):
-        self.begin(self.DEVICE)
-        try:
-            yield
-        finally:
-            self.end(self.DEVICE)
+        return span("repro.sink.device_wait", self._stats, "device_wait_s",
+                    edges=self._edges(self.DEVICE))
+
+
+@dataclasses.dataclass
+class _PutBusy:
+    """One partition store worker's busy time in batched puts."""
+    put_s: float = 0.0          # repro.store.put
 
 
 class ReadTicket:
@@ -227,8 +245,7 @@ class ReadTicket:
     requested key order (``None`` for absent keys).
     """
 
-    def __init__(self, n_keys: int, n_parts: int,
-                 stats: Optional[SinkStats] = None):
+    def __init__(self, n_keys: int, n_parts: int, stats: SinkStats):
         self._rows: List[Optional[bytes]] = [None] * n_keys
         self._pending = n_parts
         self._done = threading.Event()
@@ -255,10 +272,8 @@ class ReadTicket:
                 self._done.set()
 
     def result(self) -> List[Optional[bytes]]:
-        t0 = time.perf_counter()
-        self._done.wait()
-        if self._stats is not None:
-            self._stats.read_wait_s += time.perf_counter() - t0
+        with span("repro.sink.read_wait", self._stats, "read_wait_s"):
+            self._done.wait()
         if self._exc is not None:
             raise RuntimeError("hydration read failed") from self._exc
         return self._rows
@@ -404,7 +419,7 @@ class WriteBehindSink:
         self._unsynced = 0
         self._unsynced_cv = threading.Condition()
         self.stats = SinkStats()
-        self.overlap = _OverlapMeter()
+        self.overlap = _OverlapMeter(self.stats)
         # epoch-gated read lane (see ``stage_epoch``): key -> epoch of the
         # latest *staged* flush containing that key.  Written only by the
         # single staging thread; sized on demand.
@@ -413,7 +428,7 @@ class WriteBehindSink:
         self._applied = [0] * len(self.stores)
         self._park_lock = [threading.Lock() for _ in self.stores]
         self._parked: List[List[tuple]] = [[] for _ in self.stores]
-        self._put_busy = [0.0] * len(self.stores)
+        self._put_busy = [_PutBusy() for _ in self.stores]
         self._exc: Optional[BaseException] = None
         self._closed = False
         self._serial = queue_depth == 0
@@ -471,13 +486,12 @@ class WriteBehindSink:
             # measured-IO admission: hold the driver until the store
             # workers have landed (and fsynced) enough outstanding bytes.
             # A single oversized block still passes at zero outstanding.
-            t0 = time.perf_counter()
             self.stats.admission_waits += 1
-            with self._unsynced_cv:
+            with span("repro.sink.submit", self.stats, "submit_wait_s"), \
+                    self._unsynced_cv:
                 while (self._unsynced > self._max_unsynced
                        and self._exc is None):
                     self._unsynced_cv.wait(0.05)
-            self.stats.submit_wait_s += time.perf_counter() - t0
             self._check()
         if self._serial:
             self._flush_block(keys, z, valid, rows, seq)
@@ -487,18 +501,16 @@ class WriteBehindSink:
             # order and the one-thread-per-store invariant — the workers
             # are idle once the queues join), then flush this block inline
             # on the driver thread instead of blocking behind the queue
-            t0 = time.perf_counter()
-            self._q.join()
-            for sq in self._store_qs:
-                sq.join()
-            self._check()
-            self.stats.degraded_flushes += 1
-            self._flush_block(keys, z, valid, rows, seq, inline=True)
-            self.stats.submit_wait_s += time.perf_counter() - t0
+            with span("repro.sink.submit", self.stats, "submit_wait_s"):
+                self._q.join()
+                for sq in self._store_qs:
+                    sq.join()
+                self._check()
+                self.stats.degraded_flushes += 1
+                self._flush_block(keys, z, valid, rows, seq, inline=True)
             return
-        t0 = time.perf_counter()
-        self._q.put(("block", keys, z, valid, rows, seq))
-        self.stats.submit_wait_s += time.perf_counter() - t0
+        with span("repro.sink.submit", self.stats, "submit_wait_s"):
+            self._q.put(("block", keys, z, valid, rows, seq))
 
     def stage_epoch(self, keys, valid=None) -> int:
         """Record one flush group as *staged* and return its epoch.
@@ -716,8 +728,7 @@ class WriteBehindSink:
         """
         agg = {"puts": 0, "gets": 0, "batch_puts": 0, "batch_gets": 0,
                "bytes_written": 0, "bytes_read": 0, "modeled_io_s": 0.0,
-               "modeled_read_s": 0.0, "modeled_write_s": 0.0,
-               "store_serde_s": 0.0}
+               "modeled_read_s": 0.0, "modeled_write_s": 0.0}
         for s in self.stores:
             c = s.counters
             agg["puts"] += c.puts
@@ -729,19 +740,20 @@ class WriteBehindSink:
             agg["modeled_io_s"] += c.modeled_io_s
             agg["modeled_read_s"] += c.modeled_read_s
             agg["modeled_write_s"] += c.modeled_write_s
-            agg["store_serde_s"] += c.serde_s
         agg["waf"] = max((s.waf() for s in self.stores), default=1.0)
-        agg["put_s"] = sum(self._put_busy)
+        agg["put_s"] = sum(b.put_s for b in self._put_busy)
         # per-partition critical path: store workers run concurrently, so
         # the pipeline is bounded by the slowest store's put busy time +
         # modeled IO, not by their sum
         agg["store_path_s_max"] = max(
-            (busy + s.counters.modeled_io_s
-             for busy, s in zip(self._put_busy, self.stores)), default=0.0)
+            (b.put_s + s.counters.modeled_io_s
+             for b, s in zip(self._put_busy, self.stores)), default=0.0)
         # measured durability counters (durable backend only; the base
         # KVStore reports {}): summed across partitions, plus the measured
         # WAF — physical WAL+segment bytes per logical byte ingested —
-        # reported *next to* the modeled ``waf`` column, never replacing it
+        # reported *next to* the modeled ``waf`` column, never replacing it;
+        # the bytes written are every file the store writes: WAL, segments
+        # and their sidecar indexes
         measured: dict = {}
         per_part = [s.measured() for s in self.stores]
         for m in per_part:
@@ -749,7 +761,8 @@ class WriteBehindSink:
                 measured[k] = measured.get(k, 0) + v
         if measured:
             measured["measured_bytes_written"] = (
-                measured.get("wal_bytes", 0) + measured.get("seg_bytes", 0))
+                measured.get("wal_bytes", 0) + measured.get("seg_bytes", 0)
+                + measured.get("seg_index_bytes", 0))
             measured["measured_waf"] = (
                 measured["measured_bytes_written"]
                 / max(agg["bytes_written"], 1))
@@ -764,9 +777,7 @@ class WriteBehindSink:
                  "fsyncs": m.get("fsyncs", 0)} if m else {}
                 for m in per_part]
         agg["unsynced_bytes"] = self._unsynced
-        # host/device split: totals + measured wall-clock intersection
-        self.stats.host_pack_s = self.overlap.total[_OverlapMeter.HOST]
-        self.stats.device_wait_s = self.overlap.total[_OverlapMeter.DEVICE]
+        # host/device split: the measured wall-clock intersection
         self.stats.overlap_s = self.overlap.overlap_s
         self.stats.overlap_frac = (
             self.stats.overlap_s / self.stats.host_pack_s
@@ -953,11 +964,10 @@ class WriteBehindSink:
         bytes into its L2 cache — insertion at put *execution* time on the
         partition's single writer thread is what keeps every later ordered
         read's L2 view identical to the store's."""
-        t0 = time.perf_counter()
-        self._with_retry(self.stores[p].multi_put, keys, rows)
-        if self.l2 is not None:
-            self.l2[p].put_rows(keys, rows)
-        self._put_busy[p] += time.perf_counter() - t0
+        with span("repro.store.put", self._put_busy[p], "put_s"):
+            self._with_retry(self.stores[p].multi_put, keys, rows)
+            if self.l2 is not None:
+                self.l2[p].put_rows(keys, rows)
 
     def _exec_get(self, p: int, keys):
         """Execute one partition's batched hydration read, L2 first.
@@ -986,69 +996,69 @@ class WriteBehindSink:
 
     def _flush_block(self, keys, z, valid, rows, seq: Optional[int] = None,
                      inline: bool = False) -> None:
-        t0 = time.perf_counter()
-        # flush groups arrive with z shaped [G, B]; lanes are flat below.
-        # The np.asarray conversions below are the sink-gather sync
-        # points: materializing ``z`` (and the gathered rows) waits for
-        # the group's device compute, so they run under the overlap
-        # meter's device channel — that wait is exactly the device time
-        # a pipelined driver can hide host pack work beneath.
-        with self.overlap.device():
-            keys = np.asarray(keys).reshape(-1)
-            z = np.asarray(z).reshape(-1)
-        valid = np.asarray(valid).reshape(-1)
         st = self.stats
-        st.blocks += 1
-        st.events_seen += int(valid.sum())
-        selected = valid & (np.ones_like(z) if self.full_stream else z)
-        idx = np.nonzero(selected)[0]
-        st.selected += idx.size
-        if idx.size:
-            # last-write-wins dedupe: rows are end-of-block snapshots, so
-            # any one lane of a key already holds the key's final row.
-            uk, first = np.unique(keys[idx], return_index=True)
-            pick = idx[first]
-            st.rows_stored += uk.size
-            st.dedup_saved += idx.size - uk.size
-            if len(rows) == 2:
-                # stacked driver form: (scalars[4, B], agg[B, T, 3]), or
-                # [4, G, W] / [G, W, T, 3] from the sharded gather.
-                # Fetched whole-block (two fixed-shape host reads) —
-                # selecting on device first would re-trace a gather per
-                # distinct selection size, which costs far more than the
-                # copy.
-                with self.overlap.device():
-                    scal = np.asarray(rows[0]).reshape(4, -1)[:, pick]
-                    agg = np.asarray(rows[1])
-                    agg = agg.reshape((-1,) + agg.shape[-2:])[pick]
-                last_t, v_f, v_full, last_t_full = scal
-            else:
-                with self.overlap.device():
-                    last_t, v_f, agg, v_full, last_t_full = \
-                        tuple(np.asarray(r)[pick] for r in rows)
-            if not self.full_stream:
-                # control column is not durable under thinning policies
-                v_full = np.zeros_like(v_full)
-                last_t_full = np.full_like(last_t_full, -np.inf)
-            ts = time.perf_counter()
-            packed = self.serde.pack_rows(last_t, v_f, agg, v_full,
-                                          last_t_full)
-            st.serde_s += time.perf_counter() - ts
-            part = self._partition_fn(uk)
-            for p in np.unique(part):
-                m = part == p
-                self._put(int(p), uk[m], packed[m], inline=inline)
-        if seq is not None:
-            # epoch marker trails the block's puts on *every* partition
-            # (even ones this block wrote nothing to): once a partition
-            # processes it, every put of epochs <= seq has executed there
-            if self._serial or inline:
-                for p in range(len(self.stores)):
-                    self._mark_applied(p, seq)
-            else:
-                for sq in self._store_qs:
-                    sq.put(("epoch", seq))
-        st.flush_s += time.perf_counter() - t0
+        with span("repro.sink.flush", st, "flush_s", count="blocks"):
+            # flush groups arrive with z shaped [G, B]; lanes are flat
+            # below.  Materializing ``z`` waits for the group's device
+            # compute, so it runs under the overlap meter's device
+            # channel — that wait is exactly the device time a pipelined
+            # driver can hide host pack work beneath.  The gathered rows
+            # come from the same program: converting them after it is
+            # the copy alone.
+            keys = np.asarray(keys).reshape(-1)
+            with self.overlap.device():
+                z = np.asarray(z).reshape(-1)
+            valid = np.asarray(valid).reshape(-1)
+            selected = valid & (np.ones_like(z) if self.full_stream else z)
+            idx = np.nonzero(selected)[0]
+            st.selected += idx.size
+            if idx.size:
+                # last-write-wins dedupe: rows are end-of-block snapshots,
+                # so any one lane of a key already holds its final row.
+                uk, first = np.unique(keys[idx], return_index=True)
+                pick = idx[first]
+                st.rows_stored += uk.size
+                st.dedup_saved += idx.size - uk.size
+                st.rows_d2h_bytes += sum(int(r.nbytes) for r in rows
+                                         if isinstance(r, jax.Array))
+                with span("repro.sink.rows_d2h", st, "rows_d2h_s"):
+                    if len(rows) == 2:
+                        # stacked driver form: (scalars[4, B], agg[B, T,
+                        # 3]), or [4, G, W] / [G, W, T, 3] from the
+                        # sharded gather.  Fetched whole-block (two
+                        # fixed-shape host reads) — selecting on device
+                        # first would re-trace a gather per distinct
+                        # selection size, which costs far more than the
+                        # copy.
+                        scal = np.asarray(rows[0]).reshape(4, -1)[:, pick]
+                        agg = np.asarray(rows[1])
+                        agg = agg.reshape((-1,) + agg.shape[-2:])[pick]
+                        last_t, v_f, v_full, last_t_full = scal
+                    else:
+                        last_t, v_f, agg, v_full, last_t_full = \
+                            tuple(np.asarray(r)[pick] for r in rows)
+                if not self.full_stream:
+                    # control column is not durable under thinning policies
+                    v_full = np.zeros_like(v_full)
+                    last_t_full = np.full_like(last_t_full, -np.inf)
+                with span("repro.sink.serde", st, "serde_s"):
+                    packed = self.serde.pack_rows(last_t, v_f, agg, v_full,
+                                                  last_t_full)
+                part = self._partition_fn(uk)
+                for p in np.unique(part):
+                    m = part == p
+                    self._put(int(p), uk[m], packed[m], inline=inline)
+            if seq is not None:
+                # epoch marker trails the block's puts on *every*
+                # partition (even ones this block wrote nothing to): once
+                # a partition processes it, every put of epochs <= seq
+                # has executed there
+                if self._serial or inline:
+                    for p in range(len(self.stores)):
+                        self._mark_applied(p, seq)
+                else:
+                    for sq in self._store_qs:
+                        sq.put(("epoch", seq))
 
 
 def hydrate_state(stores: Sequence[KVStore], num_rows: int, n_taus: int,
