@@ -25,7 +25,11 @@ becomes a micro-batched tensor program.  Two execution modes are provided:
   batch-start state (decision staleness <= one batch), after which persisted
   contributions fold into the state with a *closed-form segment reduction*:
   because the HT update is a first-order linear recurrence, the end-of-batch
-  state needs only a decay-weighted segment sum, no sequential scan.  This is
+  state needs only a decay-weighted segment sum, no sequential scan.  The
+  batch is segmented once by key (one sort of its B keys), the sums run over
+  [B] segment buffers, and only the rows of the batch's keys are gathered,
+  recomputed and scattered back: the fold is O(B log B) whatever the table
+  size, and a row no event touches keeps its bits.  This is
   the production configuration (it is also what any asynchronous real system
   effectively does) and its staleness bias is bounded by the batch horizon.
 
@@ -252,10 +256,29 @@ def _step_exact(cfg: EngineConfig, impl: str, chunk: int, state: ProfileState,
     return state, info
 
 
+def _segment(key: jax.Array, valid: jax.Array, num_e: int):
+    """Segment a batch by key: one segment per distinct valid key.
+
+    Returns the segment id of each lane (int32 [B]) and the key of each
+    segment (int32 [B]).  Segments are numbered in key order; invalid lanes
+    share the last segment, whose key is ``num_e``, as is every unused one.
+    """
+    B = key.shape[0]
+    key = jnp.where(valid, key, num_e)
+    order = jnp.argsort(key)
+    key_s = key[order]
+    is_start = jnp.concatenate([jnp.array([True]), key_s[1:] != key_s[:-1]])
+    seg_s = jnp.cumsum(is_start, dtype=jnp.int32) - 1
+    seg = jnp.zeros((B,), jnp.int32).at[order].set(seg_s)
+    seg_key = jnp.full((B,), num_e, key.dtype).at[seg_s].set(key_s)
+    return seg, seg_key
+
+
 def _step_fast(cfg: EngineConfig, state: ProfileState, ev: Event, rng,
                rng_entity=None):
     taus = jnp.asarray(cfg.taus, jnp.float32)
     num_e = state.num_entities
+    B = ev.key.shape[0]
     ent = ev.key if rng_entity is None else rng_entity
     safe_key = jnp.where(ev.valid, ev.key, 0)
 
@@ -269,57 +292,58 @@ def _step_fast(cfg: EngineConfig, state: ProfileState, ev: Event, rng,
         (_, _, _, z, p, feats, lam, _, _) = _fused_rmw(
             cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
 
-    # --- closed-form segment fold of persisted contributions -------------
-    # Final per-key timestamp among persisted events:
+    # --- closed-form fold over the batch's keys --------------------------
+    # Reductions run into [B] segment buffers (one segment per distinct key,
+    # summed in lane order) and only the segments' rows are rewritten; the
+    # rows of segments with nothing to fold go to row num_e and are dropped.
     with jax.named_scope("fold"):
-        t_star = jnp.full((num_e + 1,), -jnp.inf).at[
-            jnp.where(z, ev.key, num_e)].max(ev.t)[:num_e]
+        seg, seg_key = _segment(ev.key, ev.valid, num_e)
+        old = jnp.minimum(seg_key, num_e - 1)     # gather-safe segment rows
+        # Final per-key timestamp among persisted events:
+        zseg = jnp.where(z, seg, B)
+        t_star = jnp.full((B,), -jnp.inf).at[zseg].max(ev.t, mode="drop")
         wrote = jnp.isfinite(t_star)
         t_ref = jnp.where(wrote, t_star, 0.0)
+        row = jnp.where(wrote, seg_key, num_e)
+        last_t_old = state.last_t[old]
 
         inv_p = jnp.where(z, 1.0 / p, 0.0)
         # v_f: sum_i (1/p_i) exp(-(t* - t_i)/h) + decay(t* - last_t) * v_f
-        w_v = inv_p * intensity.decay(t_ref[safe_key] - ev.t, cfg.h)
-        v_add = jnp.zeros((num_e + 1,)).at[
-            jnp.where(z, ev.key, num_e)].add(w_v)[:num_e]
-        v_f_new = jnp.where(
-            wrote,
-            v_add + intensity.decay(t_star - state.last_t, cfg.h)
-            * state.v_f,
-            state.v_f)
+        w_v = inv_p * intensity.decay(t_ref[seg] - ev.t, cfg.h)
+        v_add = jnp.zeros((B,)).at[zseg].add(w_v, mode="drop")
+        v_f_rows = (v_add + intensity.decay(t_star - last_t_old, cfg.h)
+                    * state.v_f[old])
 
         # aggregates: same fold per tau/column.
         # [B, T]
-        beta_ev = intensity.decay((t_ref[safe_key] - ev.t)[:, None], taus)
+        beta_ev = intensity.decay((t_ref[seg] - ev.t)[:, None], taus)
         contrib = (inv_p[:, None, None] * beta_ev[:, :, None] *
                    jnp.stack([jnp.ones_like(ev.q), ev.q, ev.q * ev.q],
                              -1)[:, None, :])
-        agg_add = jnp.zeros((num_e + 1,) + state.agg.shape[1:]).at[
-            jnp.where(z, ev.key, num_e)].add(contrib)[:num_e]
-        agg_new = jnp.where(
-            wrote[:, None, None],
-            agg_add + estimators.decay_to(state.agg, state.last_t, t_star,
-                                          taus),
-            state.agg)
+        agg_add = jnp.zeros((B,) + state.agg.shape[1:]).at[zseg].add(
+            contrib, mode="drop")
+        agg_rows = agg_add + estimators.decay_to(state.agg[old], last_t_old,
+                                                 t_star, taus)
 
-        last_t_new = jnp.where(wrote, t_star, state.last_t)
+        last_t_new = state.last_t.at[row].set(t_star, mode="drop")
+        v_f_new = state.v_f.at[row].set(v_f_rows, mode="drop")
+        agg_new = state.agg.at[row].set(agg_rows, mode="drop")
 
     # full-stream control column (every valid event).
     with jax.named_scope("fold_control"):
-        tf_star = jnp.full((num_e + 1,), -jnp.inf).at[
-            jnp.where(ev.valid, ev.key, num_e)].max(ev.t)[:num_e]
+        vseg = jnp.where(ev.valid, seg, B)
+        tf_star = jnp.full((B,), -jnp.inf).at[vseg].max(ev.t, mode="drop")
         saw = jnp.isfinite(tf_star)
         tf_ref = jnp.where(saw, tf_star, 0.0)
+        row_f = jnp.where(saw, seg_key, num_e)
         w_full = jnp.where(ev.valid, 1.0, 0.0) * intensity.decay(
-            tf_ref[safe_key] - ev.t, cfg.h)
-        vfull_add = jnp.zeros((num_e + 1,)).at[
-            jnp.where(ev.valid, ev.key, num_e)].add(w_full)[:num_e]
-        v_full_new = jnp.where(
-            saw,
-            vfull_add + intensity.decay(tf_star - state.last_t_full, cfg.h)
-            * state.v_full,
-            state.v_full)
-        last_t_full_new = jnp.where(saw, tf_star, state.last_t_full)
+            tf_ref[seg] - ev.t, cfg.h)
+        vfull_add = jnp.zeros((B,)).at[vseg].add(w_full, mode="drop")
+        v_full_rows = (vfull_add + intensity.decay(
+            tf_star - state.last_t_full[old], cfg.h) * state.v_full[old])
+        v_full_new = state.v_full.at[row_f].set(v_full_rows, mode="drop")
+        last_t_full_new = state.last_t_full.at[row_f].set(tf_star,
+                                                          mode="drop")
 
     state = ProfileState(last_t=last_t_new, v_f=v_f_new, agg=agg_new,
                          v_full=v_full_new, last_t_full=last_t_full_new)

@@ -1,4 +1,5 @@
 """Engine correctness: JAX vectorized modes vs the per-event Python oracle."""
+import functools
 import math
 
 import jax
@@ -310,3 +311,111 @@ def test_decision_reproducibility_across_batching():
         return np.concatenate(allz)
 
     np.testing.assert_array_equal(run(16), run(64))
+
+
+def _dense_fast_step(cfg, state, ev, rng):
+    """Fast mode with the table-wide fold it had before the segment fold:
+    [num_e + 1] accumulators and a ``where`` over every row.  The oracle of
+    ``test_fast_fold_matches_dense_fold``."""
+    from repro.core import estimators, intensity, thinning
+    from repro.core import engine as core_engine
+    from repro.core.types import ProfileState, StepInfo
+    taus = jnp.asarray(cfg.taus, jnp.float32)
+    num_e = state.num_entities
+    safe_key = jnp.where(ev.valid, ev.key, 0)
+    u = thinning.uniform_for_events(rng, safe_key,
+                                    core_engine._seq_bits(ev.t))
+    (_, _, _, z, p, feats, lam, _, _) = core_engine._fused_rmw(
+        cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
+
+    t_star = jnp.full((num_e + 1,), -jnp.inf).at[
+        jnp.where(z, ev.key, num_e)].max(ev.t)[:num_e]
+    wrote = jnp.isfinite(t_star)
+    t_ref = jnp.where(wrote, t_star, 0.0)
+    inv_p = jnp.where(z, 1.0 / p, 0.0)
+    w_v = inv_p * intensity.decay(t_ref[safe_key] - ev.t, cfg.h)
+    v_add = jnp.zeros((num_e + 1,)).at[
+        jnp.where(z, ev.key, num_e)].add(w_v)[:num_e]
+    v_f_new = jnp.where(
+        wrote, v_add + intensity.decay(t_star - state.last_t, cfg.h)
+        * state.v_f, state.v_f)
+    beta_ev = intensity.decay((t_ref[safe_key] - ev.t)[:, None], taus)
+    contrib = (inv_p[:, None, None] * beta_ev[:, :, None] *
+               jnp.stack([jnp.ones_like(ev.q), ev.q, ev.q * ev.q],
+                         -1)[:, None, :])
+    agg_add = jnp.zeros((num_e + 1,) + state.agg.shape[1:]).at[
+        jnp.where(z, ev.key, num_e)].add(contrib)[:num_e]
+    agg_new = jnp.where(
+        wrote[:, None, None],
+        agg_add + estimators.decay_to(state.agg, state.last_t, t_star, taus),
+        state.agg)
+    last_t_new = jnp.where(wrote, t_star, state.last_t)
+
+    tf_star = jnp.full((num_e + 1,), -jnp.inf).at[
+        jnp.where(ev.valid, ev.key, num_e)].max(ev.t)[:num_e]
+    saw = jnp.isfinite(tf_star)
+    tf_ref = jnp.where(saw, tf_star, 0.0)
+    w_full = jnp.where(ev.valid, 1.0, 0.0) * intensity.decay(
+        tf_ref[safe_key] - ev.t, cfg.h)
+    vfull_add = jnp.zeros((num_e + 1,)).at[
+        jnp.where(ev.valid, ev.key, num_e)].add(w_full)[:num_e]
+    v_full_new = jnp.where(
+        saw, vfull_add + intensity.decay(tf_star - state.last_t_full, cfg.h)
+        * state.v_full, state.v_full)
+    last_t_full_new = jnp.where(saw, tf_star, state.last_t_full)
+
+    state = ProfileState(last_t=last_t_new, v_f=v_f_new, agg=agg_new,
+                         v_full=v_full_new, last_t_full=last_t_full_new)
+    return state, StepInfo(z=z, p=p, lam_hat=lam, features=feats,
+                           writes=jnp.sum(z).astype(jnp.int32))
+
+
+@pytest.mark.parametrize("n_entities", [12, 5000])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_fast_fold_matches_dense_fold(policy, n_entities):
+    """The fast fold touches only the batch's keys, yet gives what the
+    table-wide fold gives: the same decisions, the same state within float32
+    reordering, and untouched rows bit for bit.  Keys repeat within a batch
+    (Zipf), some lanes are invalid and later batches end in padding, with
+    fewer keys than lanes and with many more."""
+    rng = np.random.default_rng(17)
+    batch, n_batches = 64, 24
+    keys, qs, ts = _make_stream(rng, batch * n_batches, n_entities,
+                                skew=1.1)
+    cfg = EngineConfig(taus=(60.0, 3600.0, 86400.0), h=600.0, budget=2e-4,
+                       alpha=1.0, policy=policy, fixed_rate=0.3,
+                       mu_tau_index=1)
+    root = jax.random.PRNGKey(29)
+    step = jax.jit(make_step(cfg, "fast"))
+    dense = jax.jit(functools.partial(_dense_fast_step, cfg))
+    state = init_state(n_entities, len(cfg.taus))
+    thinned = 0
+    for b in range(n_batches):
+        sl = slice(b * batch, (b + 1) * batch)
+        valid = rng.random(batch) > 0.15
+        if b >= n_batches // 2:
+            valid[batch - 9:] = False           # a padded tail
+        k = np.where(valid, keys[sl], 0).astype(np.int32)
+        ev = Event(key=jnp.asarray(k), q=jnp.asarray(qs[sl]),
+                   t=jnp.asarray(np.where(valid, ts[sl], 0.0)
+                                 .astype(np.float32)),
+                   valid=jnp.asarray(valid))
+        got, info = step(state, ev, root)
+        want, winfo = dense(state, ev, root)
+        for f in ("z", "p", "lam_hat", "features", "writes"):
+            np.testing.assert_array_equal(np.asarray(getattr(info, f)),
+                                          np.asarray(getattr(winfo, f)),
+                                          err_msg=f)
+        z = np.asarray(info.z)
+        persisted = np.isin(np.arange(n_entities), k[z])
+        seen = np.isin(np.arange(n_entities), k[valid])
+        for name, a, w, before in zip(got._fields, got, want, state):
+            a, w, before = map(np.asarray, (a, w, before))
+            np.testing.assert_allclose(a, w, rtol=1e-6, atol=0,
+                                       err_msg=name)
+            kept = ~(seen if name.endswith("_full") else persisted)
+            np.testing.assert_array_equal(a[kept], before[kept],
+                                          err_msg=name)
+        thinned += int((valid & ~z).sum())
+        state = got
+    assert thinned > 0 or policy in ("full", "unfiltered")
