@@ -1,25 +1,49 @@
 """One run of one cell: everything between the command line and the result.
 
-The harness is driven by ``BENCHMARK.json``.  A cell names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); every metric is read by a reader of its
-own (``bench/metrics/<metric>.py``, a function ``read(rec)`` that returns
-the value or ``None`` where it finds nothing to read); every cell's
-correctness limits are in ``bench/limits/<cell>.json``.  Adding a cell,
-configuration, traffic mix or metric adds files, and edits none.
+The harness is driven by ``BENCHMARK.json``, and finds everything a cell
+needs by name, in files under the root it is given:
+
+- A cell names a configuration (the file its ``configs`` entry gives) and
+  a traffic mix (``bench/traffic/<traffic>.json``).
+- The traffic's ``"driver"`` names the timed path, a module
+  ``bench/paths/<driver>.py`` with ``events(config, traffic, seconds)``, the
+  stream's events or requests (a window may take the stream more than
+  once: ``attempted`` is the window's own count); ``prepare(config,
+  traffic, stream, followed, *, seed, seed32, rng, seconds, devices,
+  tmp)``, the set-up before the window opens (``devices`` are the cell's
+  chips, ``tmp`` a directory the run removes); and ``window(run,
+  stream)``, the timed window, which returns a ``drivers.Window``.
+- The configuration names, by path, its ``"generator"``, with
+  ``make_stream(config, n_events, seed)``; its plain ``"reference"``, with
+  ``engine_key_data(seed)`` (the 32-bit key data of the program's random
+  draws and the reference's) and ``CONTROL`` (the precision of the check's
+  control); and its ``"check"``, with ``follow(config, traffic, stream,
+  seed)`` (what the comparison follows, handed to the driver's
+  ``prepare``), ``check(reference, config, traffic, window, stream, seed32,
+  followed, limits)`` (the numbers ``compare.judge`` holds to the limits,
+  and the diagnostics) and ``control(reference, config, traffic, stream,
+  seed, followed, limits)`` (the same numbers, with the reference in its
+  control precision in the program's place).
+- Every metric is read by a reader of its own
+  (``bench/metrics/<metric>.py``, a function ``read(rec)`` that returns the
+  value or ``None`` where it finds nothing to read), and every cell's
+  correctness limits are in ``bench/limits/<cell>.json``.
+
+Adding a cell, configuration, traffic mix, driver or metric adds files,
+and edits none.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import os
+import re
 import sys
 import tempfile
 import time
 from typing import Optional
-
-import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -43,8 +67,46 @@ def cell_spec(name: str, root: str = ROOT) -> tuple:
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(root, configs[cell["config"]]["file"])
-    traffic = load_json(BENCH, "traffic", f"{cell['traffic']}.json")
+    traffic = load_json(root, "bench", "traffic", f"{cell['traffic']}.json")
     return bench, cell, config, traffic
+
+
+def module(root: str, path: str):
+    """The module in the file ``path`` under ``root``.  Inside this
+    checkout, where each part of the path is a name, it is imported by its
+    dotted name, so that it is the module the benchmark's own imports
+    share; otherwise it is loaded from its file."""
+    full = os.path.realpath(os.path.join(root, path))
+    stem, ext = os.path.splitext(os.path.relpath(full, os.path.realpath(
+        ROOT)))
+    if ext != ".py":
+        raise ValueError(f"{path} is not a Python file")
+    parts = stem.split(os.sep)
+    if all(p.isidentifier() for p in parts):
+        return importlib.import_module(".".join(parts))
+    name = "bench_file_" + re.sub(r"\W", "_", full)
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, full)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def driver(root: str, traffic: dict):
+    """The timed path the traffic names."""
+    return module(root, os.path.join("bench", "paths",
+                                     f"{traffic['driver']}.py"))
+
+
+def deployment(root: str, config: dict) -> tuple:
+    """The (generator, reference, check) modules the configuration names."""
+    return tuple(module(root, config[k])
+                 for k in ("generator", "reference", "check"))
 
 
 def metrics_of(bench: dict, cell: str, traced: bool) -> list:
@@ -53,13 +115,8 @@ def metrics_of(bench: dict, cell: str, traced: bool) -> list:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
-def reader(name: str):
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+def reader(name: str, root: str = ROOT):
+    return module(root, os.path.join("bench", "metrics", f"{name}.py")).read
 
 
 @dataclasses.dataclass
@@ -89,51 +146,34 @@ def check_chips(chips: int):
 
 
 def run_cell(name: str, seed: int, seconds: float, traced: bool,
-             t_start: float, require_chip: bool = True) -> dict:
+             t_start: float, require_chip: bool = True,
+             root: str = ROOT) -> dict:
     import jax
 
-    bench, cell, config, traffic = cell_spec(name)
-    devs = check_chips(int(cell["chips"])) if require_chip else \
-        jax.devices()
+    bench, cell, config, traffic = cell_spec(name, root)
+    chips = int(cell["chips"])
+    devs = check_chips(chips) if require_chip else jax.devices()
     from repro.launch.compile_cache import enable_compile_cache
 
-    from bench import compare, drivers, reference, streamgen, trace_reduce
+    from bench import compare, drivers, trace_reduce
 
     enable_compile_cache()
+    path = driver(root, traffic)
+    generator, reference, chk = deployment(root, config)
     seed32 = reference.engine_key_data(seed)
     rng = jax.random.PRNGKey(seed32)
-    st = config["stream"]
-    kind = traffic["driver"]
-    if kind == "ingest":
-        chunk = (int(config["engine"]["batch"])
-                 * int(config["engine"]["sink_group"])
-                 * int(traffic["chunk_groups"]))
-        n_events = chunk * max(2, -(-int(round(
-            float(traffic["window_events_per_s"]) * seconds)) // chunk))
-    elif kind == "serve":
-        n_events = int(round(float(traffic["rate_per_s"]) * seconds))
-    else:
-        raise ValueError(f"unknown driver {kind!r}")
-    spec = streamgen.StreamSpec.from_config(st, n_events)
+    n_events = path.events(config, traffic, seconds)
     t_gen = time.perf_counter()
     with drivers.annotate("bench.generate"):
-        stream = streamgen.generate(spec, seed)
+        stream = generator.make_stream(config, n_events, seed)
     t_warm = time.perf_counter()
-    keys_followed = compare.sample_keys(
-        seed, spec.n_keys, float(traffic["key_share"]), stream.key)
-    sampled = np.zeros(spec.n_keys, bool)
-    sampled[keys_followed] = True
+    followed = chk.follow(config, traffic, stream, seed)
+    limits = compare.load_limits(os.path.join(root, "bench"), name)
 
     with tempfile.TemporaryDirectory(prefix="bench-") as tmp:
-        store_dir = os.path.join(tmp, "store")
-        if kind == "ingest":
-            run = drivers.prepare_ingest(config, traffic, stream, rng,
-                                         os.path.join(tmp, "warm"),
-                                         store_dir)
-        else:
-            run = drivers.prepare_serve(config, traffic, stream, rng,
-                                        seed32, os.path.join(tmp, "warm"))
-            due = drivers.arrivals(n_events, seconds, seed)
+        run = path.prepare(config, traffic, stream, followed, seed=seed,
+                           seed32=seed32, rng=rng, seconds=seconds,
+                           devices=devs[:chips], tmp=tmp)
         setup_s = time.perf_counter() - t_start
         parts = {"start_s": t_gen - t_start, "generate_s": t_warm - t_gen,
                  "warmup_s": setup_s - (t_warm - t_start)}
@@ -141,22 +181,15 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
         if traced:
             trace_dir = os.path.join(tmp, "trace")
             trace_reduce.start(trace_dir)
-        if kind == "ingest":
-            win = drivers.ingest(run, stream, sampled, store_dir)
-        else:
-            win = drivers.serve(run, stream, due, sampled, store_dir)
+        win = path.window(run, stream)
         if traced:
             jax.profiler.stop_trace()
         peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-                   for d in devs[:int(cell["chips"])])
-        scorer = getattr(getattr(run, "pipe", None), "scorer", None)
-        scorer = None if scorer is None else reference.Scorer(
-            **{f: np.asarray(getattr(scorer, f), np.float64)
-               for f in scorer._fields})
+                   for d in devs[:chips])
         del run
 
-        numbers, info = check(config, win, stream, seed32, keys_followed,
-                              scorer, compare.load_limits(BENCH, name))
+        numbers, info = chk.check(reference, config, traffic, win, stream,
+                                  seed32, followed, limits)
         summary = None
         if traced:
             summary = trace_reduce.summarize(trace_reduce.load(
@@ -165,11 +198,10 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     rec = Record(cell=cell, config=config, traffic=traffic, window=win,
                  setup_s=setup_s, peak_bytes=peak,
                  device_kind=devs[0].device_kind, trace=summary)
-    limits = compare.load_limits(BENCH, name)
     correct, table = compare.judge(numbers, limits)
     metrics = {}
     for m in metrics_of(bench, name, traced):
-        value = reader(m["name"])(rec)
+        value = reader(m["name"], root)(rec)
         if value is None:
             if not traced:
                 raise RuntimeError(f"end-to-end metric {m['name']} has no "
@@ -177,7 +209,7 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
             continue
         metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": int(cell["chips"]), "memory_peak_bytes": peak}
+              "count": chips, "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": int(win.events),
               "failed": int(win.events - win.completed),
               "metrics": metrics, "device": device}
@@ -192,65 +224,6 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
         store_bytes=int(win.store_bytes))
     result["check"] = table
     return result
-
-
-def check(config: dict, win, stream, seed32: int, keys_followed, scorer,
-          limits: dict) -> tuple:
-    """The comparison's numbers, and what the diagnostics line shows."""
-    import jax
-    from repro.streaming.durable import open_partition_stores
-    from repro.streaming.persistence import hydrate_state
-
-    from bench import compare, reference
-
-    eng = reference.EngineParams.from_config(config["engine"])
-    pos = win.sample_pos
-    keys, q, t = stream.key[pos], stream.q[pos], stream.t[pos]
-    u = reference.uniforms(seed32, keys, t)
-    ref = reference.FastReference(eng, keys_followed)
-    dec = ref.run(keys, q, t, u, win.batch_id)
-    rows = ref.rows(keys)
-    fol = compare.follow(dec, u, rows, len(keys_followed), win.z,
-                         limits["p_rel_err"])
-    numbers = compare.decisions(dec, fol, win.p, win.z, win.lam, u,
-                                limits["p_rel_err"])
-    whole = compare.follow(dec, u, rows, len(keys_followed), win.z,
-                           limits["p_rel_err"], writes=np.inf)
-    whole = compare.decisions(dec, whole, win.p, win.z, win.lam, u,
-                              limits["p_rel_err"])
-
-    n_keys = int(config["stream"]["n_keys"])
-    n_taus = len(eng.taus)
-    stores = open_partition_stores(win.store_dir, 1)
-    try:
-        with jax.default_device(jax.devices("cpu")[0]):
-            hyd = hydrate_state(stores, n_keys, n_taus)
-            hyd = {f: np.asarray(getattr(hyd, f)) for f in hyd._fields}
-    finally:
-        for s in stores:
-            s.close()
-    at = {f: v[keys_followed] for f, v in hyd.items()}
-    numbers["store_rel_err"] = compare.rows_gap(
-        ref, fol, at, names=("last_t", "v_f", "agg"))
-    if win.state is not None:
-        final = {f: v[keys_followed] for f, v in win.state.items()}
-        numbers["state_rel_err"] = compare.rows_gap(ref, fol, final)
-        numbers["full_rel_err"] = compare.full_gap(ref, final)
-        off = np.zeros(n_keys, bool)
-        for f in ("last_t", "v_f", "agg"):
-            a, b = hyd[f], win.state[f]
-            diff = a.view(np.uint32) != b.view(np.uint32)
-            off |= diff.reshape(n_keys, -1).any(axis=1)
-        numbers["store_rows_off"] = int(off.sum())
-    if win.scores is not None:
-        numbers["score_err"] = compare.scores(dec, fol, scorer, win.scores)
-        numbers["order_off"] = int(win.order_off)
-    info = {"compared_events": int(fol.event_in.sum()),
-            "sampled_events": int(len(pos)),
-            "followed_keys": int(len(keys_followed)),
-            "keys_left": fol.keys_left, "flips_forgiven": fol.forgiven,
-            "whole_history": whole}
-    return numbers, info
 
 
 def main(argv=None, t_start: Optional[float] = None) -> int:

@@ -33,6 +33,9 @@ import ml_dtypes
 import numpy as np
 
 BFLOAT16 = np.dtype(ml_dtypes.bfloat16)
+# the correctness check's control: this reference in the program's place,
+# in the precision below the float32 the configurations state
+CONTROL = BFLOAT16
 
 
 @dataclasses.dataclass(frozen=True)

@@ -54,6 +54,11 @@ class Stream:
         return len(self.key)
 
 
+def make_stream(config: dict, n_events: int, seed: int) -> Stream:
+    """``n_events`` of the configuration's stream, from ``seed``."""
+    return generate(StreamSpec.from_config(config["stream"], n_events), seed)
+
+
 def zipf_weights(n_keys: int, a: float) -> np.ndarray:
     w = 1.0 / np.arange(1, n_keys + 1, dtype=np.float64) ** a
     return w / w.sum()

@@ -1,5 +1,6 @@
-"""Helpers shared by the benchmark's CPU tests: import paths and cells cut
-to a size the CPU runs in seconds (same layers, same code paths)."""
+"""Helpers shared by the benchmark's CPU tests: import paths, and the
+cells the tests drive, cut to the size their files' ``"cpu"`` blocks give
+(same layers, same code paths, seconds on the CPU)."""
 import copy
 import os
 import sys
@@ -12,36 +13,44 @@ for p in (ROOT, os.path.join(ROOT, "src")):
 
 from bench import harness  # noqa: E402
 
-SMALL_KEYS = 4000
-SMALL_EVENTS = 200_000          # per second of the window
-# the cells the tests drive, by their configuration and traffic files
-CELLS = {"iiot800k.ingest": ("iiot800k", "ingest"),
-         "iiot800k.ingest_filled": ("iiot800k", "ingest_filled"),
-         "iiot800k.serve": ("iiot800k", "serve_iiot800k")}
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def small_cell(monkeypatch, rate=20000.0):
+def all_cells(root=ROOT) -> dict:
+    """BENCHMARK.json's cells, and those left out of it whose files are
+    kept (``kept_cells.json``), by name."""
+    bench = harness.load_json(root, "BENCHMARK.json")
+    kept = harness.load_json(HERE, "kept_cells.json")
+    return {w["name"]: w for w in bench["workloads"] + kept}
+
+
+CELLS = sorted(all_cells())
+
+
+def cpu_cut(doc: dict) -> dict:
+    """``doc`` with its ``"cpu"`` block laid over it, group by group."""
+    def lay(into, over):
+        for k, v in over.items():
+            if isinstance(v, dict):
+                lay(into[k], v)
+            else:
+                into[k] = v
+    out = copy.deepcopy(doc)
+    lay(out, doc["cpu"])
+    return out
+
+
+def small_spec(name, root=ROOT):
+    """``harness.cell_spec`` at the CPU size."""
+    bench = harness.load_json(root, "BENCHMARK.json")
+    cell = all_cells(root)[name]
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    config = harness.load_json(root, files[cell["config"]])
+    traffic = harness.load_json(root, "bench", "traffic",
+                                f"{cell['traffic']}.json")
+    return bench, cell, cpu_cut(config), cpu_cut(traffic)
+
+
+def small_cell(monkeypatch):
     """Make ``harness.cell_spec`` hand out CPU-sized cells."""
-    def small(name, root=harness.ROOT):
-        bench = harness.load_json(root, "BENCHMARK.json")
-        cfg, trf = CELLS[name]
-        cell = {"name": name, "config": cfg, "traffic": trf, "chips": 1}
-        config = copy.deepcopy(harness.load_json(
-            harness.BENCH, "configs", f"{cfg}.json"))
-        traffic = harness.load_json(harness.BENCH, "traffic", f"{trf}.json")
-        config["stream"].update(n_keys=SMALL_KEYS)
-        config["engine"].update(batch=512, sink_group=4)
-        if traffic["driver"] == "ingest":
-            traffic.update(chunk_groups=2, window_events_per_s=SMALL_EVENTS)
-        else:
-            traffic.update(rate_per_s=rate, warmup_requests=256, batch=64)
-        return bench, cell, config, traffic
-
-    monkeypatch.setattr(harness, "cell_spec", small)
-
-
-def cell_files(name):
-    """(config, traffic) of a cell, read from its files."""
-    cfg, trf = CELLS[name]
-    return (harness.load_json(harness.BENCH, "configs", f"{cfg}.json"),
-            harness.load_json(harness.BENCH, "traffic", f"{trf}.json"))
+    monkeypatch.setattr(harness, "cell_spec", small_spec)

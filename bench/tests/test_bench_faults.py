@@ -1,6 +1,7 @@
 """The comparison that decides ``correct``: a sound run passes, and a run
 whose timed path is broken underneath fails, once per fault the cells can
-have; the bfloat16 control fails every cell's limits.
+have; the bfloat16 control, through each configuration's reference and
+check, fails every cell's limits.
 
 Runs the harness past its look for a chip, on the CPU, at a size the CPU
 takes in seconds.  (One chip per cell: no exchange between chips to
@@ -8,7 +9,7 @@ drop.)"""
 import jax.numpy as jnp
 import pytest
 
-from benchtest import CELLS, cell_files, harness, small_cell
+from benchtest import CELLS, harness, small_cell, small_spec
 
 import repro.core.engine as engine
 import repro.core.stream as stream
@@ -72,6 +73,29 @@ def test_fault_is_not_correct(cells, monkeypatch, cell, fault):
     assert not res["correct"], res["check"]
 
 
+def test_fault_in_an_earlier_pass_is_not_correct(cells, monkeypatch):
+    """A window of several passes is held to the reference pass by pass:
+    an answer altered in the first pass alone is caught."""
+    from bench.paths import ingest
+
+    _, _, _, traffic = small_spec("iiot800k.ingest")
+    assert ingest.passes(traffic) >= 2
+    orig, seen = ingest._pass, []
+
+    def first_altered(run, stream, sink, n_chunks):
+        state, (p, z, lam), stats = orig(run, stream, sink, n_chunks)
+        if not seen:
+            z = z.copy()
+            z[::64] = ~z[::64]
+        seen.append(1)
+        return state, (p, z, lam), stats
+    monkeypatch.setattr(ingest, "_pass", first_altered)
+    res = run("iiot800k.ingest")
+    assert len(seen) == ingest.passes(traffic)
+    assert not res["correct"], res["check"]
+    assert res["check"]["z_flips"]["value"] > 0
+
+
 def test_altered_score_is_not_correct(cells, monkeypatch):
     orig = frontend.score_at_width
 
@@ -90,10 +114,9 @@ def test_altered_score_is_not_correct(cells, monkeypatch):
 def test_bfloat16_control_is_not_correct(cell):
     from bench import compare, control
 
-    config, traffic = cell_files(cell)
-    n = 300_000 if traffic["driver"] == "ingest" else 60_000
+    _, _, config, traffic = small_spec(cell)
+    n = harness.driver(harness.ROOT, traffic).events(config, traffic, 1.0)
     limits = compare.load_limits(harness.BENCH, cell)
-    numbers = control.readings(config, traffic, SEED, n,
-                               limits["p_rel_err"])
+    numbers = control.readings(config, traffic, SEED, n, limits)
     correct, table = compare.judge(numbers, limits)
     assert not correct, table

@@ -36,6 +36,13 @@ def test_names(bench, kind):
                                                              "higher")
 
 
+def four_chip_cells_allowed(workloads) -> bool:
+    """At most half of the cells, rounded down, ask for four chips; one
+    always may."""
+    four = sum(w["chips"] == 4 for w in workloads)
+    return four <= max(1, len(workloads) // 2)
+
+
 def test_every_cell_resolves(bench):
     configs = {c["name"]: c for c in bench["configs"]}
     for w in bench["workloads"]:
@@ -44,11 +51,39 @@ def test_every_cell_resolves(bench):
         assert entry["file"].startswith("bench/configs/")
         assert config["name"] == w["config"]
         assert config["reduced"] == entry["reduced"]
-        assert traffic["driver"] in ("ingest", "serve")
+        path = harness.driver(ROOT, traffic)
+        assert all(callable(getattr(path, f))
+                   for f in ("events", "prepare", "window"))
         limits = harness.load_json(harness.BENCH, "limits",
                                    f"{w['name']}.json")["limits"]
         assert limits
-        assert w["chips"] == 1
+        assert w["chips"] in (1, 4)
+    assert four_chip_cells_allowed(bench["workloads"])
+
+
+@pytest.mark.parametrize("config", sorted(os.listdir(os.path.join(
+    harness.BENCH, "configs"))))
+def test_every_config_names_its_modules(config):
+    doc = harness.load_json(harness.BENCH, "configs", config)
+    generator, reference, check = harness.deployment(ROOT, doc)
+    assert callable(generator.make_stream)
+    assert callable(reference.engine_key_data) and reference.CONTROL
+    assert all(callable(getattr(check, f))
+               for f in ("follow", "check", "control"))
+
+
+@pytest.mark.parametrize("four,allowed", [(2, True), (3, False)])
+def test_four_chip_share(tmp_path, four, allowed):
+    """A synthetic five-cell BENCHMARK.json: two four-chip cells pass,
+    three do not."""
+    cells = [{"name": f"c{i}", "config": "x", "traffic": "t",
+              "chips": 4 if i < four else 1, "why": "synthetic"}
+             for i in range(5)]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(
+        {"workloads": cells}))
+    bench = harness.load_json(tmp_path, "BENCHMARK.json")
+    assert four_chip_cells_allowed(bench["workloads"]) is allowed
+    assert four_chip_cells_allowed(bench["workloads"][:1])
 
 
 def test_every_metric_has_a_reader(bench):

@@ -42,3 +42,21 @@ def test_store_compaction_share_without_the_span():
     stats = dict(FULL, measured={"wal_bytes": 1})   # a store of the parent
     assert harness.reader("store_compaction_share.ingest")(
         record(stats)) is None
+
+
+def test_passes_stats_merge():
+    """A window of several passes reports its sinks' stats as one: counts
+    and seconds summed, ratios and maxima the largest, lists joined."""
+    from bench.paths.ingest import merge_stats
+
+    a = {"puts": 10, "outputs_s": 0.5, "waf": 1.5, "store_path_s_max": 2.0,
+         "measured": {"compaction_s": 0.25, "measured_waf": 3.0},
+         "measured_per_partition": [{"fsyncs": 1}]}
+    b = {"puts": 5, "outputs_s": 0.25, "waf": 1.25, "store_path_s_max": 3.0,
+         "measured": {"compaction_s": 0.5, "measured_waf": 2.0},
+         "measured_per_partition": [{"fsyncs": 2}]}
+    assert merge_stats([a, b]) == {
+        "puts": 15, "outputs_s": 0.75, "waf": 1.5, "store_path_s_max": 3.0,
+        "measured": {"compaction_s": 0.75, "measured_waf": 3.0},
+        "measured_per_partition": [{"fsyncs": 1}, {"fsyncs": 2}]}
+    assert merge_stats([a]) == a

@@ -72,8 +72,8 @@ def main(argv=None) -> int:
         out[name] = {"ulps": ulps(exp)}
         for writes in (compare.WRITES, np.inf):
             out[name][f"writes_{writes}"] = control.readings(
-                config, traffic, args.seed, args.events,
-                limits["p_rel_err"], np.float32, exp, writes)
+                config, traffic, args.seed, args.events, limits,
+                dtype=np.float32, exp=exp, writes=writes)
     print(json.dumps(out), flush=True)
     return 0
 
