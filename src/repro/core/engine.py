@@ -47,9 +47,13 @@ For steady-state streaming throughput use ``repro.core.stream.run_stream``,
 which scans [n_batches, B] event blocks through one jitted, state-donating
 dispatch (zero state copies between blocks).
 
-Both modes use counter-based RNG keyed on (entity, time-bits) so a given event
-receives the same thinning decision regardless of batching, ordering or shard
-placement.  The step callables accept an optional ``rng_entity`` column for
+Both modes use counter-based RNG keyed on (entity, per-event counter), the
+rule of ``core.thinning.counters``, so a given event receives the same
+thinning decision regardless of batching, ordering or shard placement.
+An event may update K profiles (``Event.key`` ``[B, K]``): the step then
+runs one lane per (event, profile) pair, event by event, each lane keyed on
+its own row and its event's counter, and returns per-event outputs
+``[B, K, ...]``.  The step callables accept an optional ``rng_entity`` column for
 callers whose ``Event.key`` is a *local* row index rather than the global
 entity id: the sharded engine passes ``local_row * n_shards + shard``, and
 the bounded-residency drivers (``core.stream.run_stream(residency=...)``)
@@ -79,11 +83,6 @@ __all__ = ["init_state", "make_step", "materialize_features"]
 # masking on the VPU.  exp(-(t + 1e38)/h) underflows to 0 exactly, so the
 # substitution is behaviour-preserving on the decay paths.
 _FRESH_SENTINEL = -1e38
-
-
-# Per-event RNG counter (single definition in core.thinning, shared with
-# the per-event worker for the persistence byte-parity contract).
-_seq_bits = thinning.time_bits
 
 
 def _fused_kw(cfg: EngineConfig) -> dict:
@@ -116,10 +115,11 @@ def _fused_rmw(cfg: EngineConfig, taus, state: ProfileState, key, q, t, u,
 def _sort_by_key_time(ev: Event):
     # Invalid (padding) lanes sort into their own trailing segment: otherwise
     # a padded tail block's key=0/t=0 filler would occupy entity 0's first
-    # round slots and push its real events past exact_rounds.
+    # round slots and push its real events past exact_rounds.  The sort is
+    # stable, so events with equal (key, t) keep their lane order.
     sort_key = jnp.where(ev.valid, ev.key, jnp.iinfo(jnp.int32).max)
     order = jnp.lexsort((ev.t, sort_key))
-    ev_s = Event(*(x[order] for x in ev))
+    ev_s = jax.tree.map(lambda x: x[order], ev)
     key_s = sort_key[order]
     idx = jnp.arange(ev.key.shape[0])
     is_start = jnp.concatenate(
@@ -168,9 +168,10 @@ def _step_exact(cfg: EngineConfig, impl: str, chunk: int, state: ProfileState,
     n_taus = taus.shape[0]
 
     # Round-invariant bookkeeping, hoisted out of the scan: the counter-based
-    # uniforms depend only on (entity, t) and the inverse sort permutation
-    # only on the batch — neither needs recomputation per round.
-    u_s = thinning.uniform_for_events(rng, ent[order], _seq_bits(ev_s.t))
+    # uniforms depend only on (entity, counter) and the inverse sort
+    # permutation only on the batch — neither needs recomputation per round.
+    u_s = thinning.uniform_for_events(rng, ent[order],
+                                      thinning.counters(ev_s.t, ev_s.ctr))
     inv = jnp.argsort(order)
 
     init = (state, jnp.zeros((B,), jnp.float32), jnp.zeros((B,), bool),
@@ -288,7 +289,7 @@ def _step_fast(cfg: EngineConfig, state: ProfileState, ev: Event, rng,
     # kernel's single-event RMW when keys repeat within the batch.
     with jax.named_scope("decide"):
         u = thinning.uniform_for_events(rng, jnp.where(ev.valid, ent, 0),
-                                        _seq_bits(ev.t))
+                                        thinning.counters(ev.t, ev.ctr))
         (_, _, _, z, p, feats, lam, _, _) = _fused_rmw(
             cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
 
@@ -352,6 +353,30 @@ def _step_fast(cfg: EngineConfig, state: ProfileState, ev: Event, rng,
     return state, info
 
 
+def _per_lane(step) -> Callable:
+    """``step`` for batches whose events update K profiles each.
+
+    A ``[B, K]``-keyed batch runs as B*K lanes, event by event (lane
+    ``b*K + k`` is event b's k-th profile, with its event's q, t, valid and
+    counter), and each per-event output comes back ``[B, K, ...]``.  A
+    ``[B]``-keyed batch goes to ``step`` as it is."""
+    def run(state: ProfileState, ev: Event, rng, rng_entity=None):
+        if ev.key.ndim == 1:
+            return step(state, ev, rng, rng_entity)
+        B, K = ev.key.shape
+        lane = lambda x: None if x is None else jnp.repeat(x, K, axis=0)
+        lanes = Event(key=ev.key.reshape(-1), q=lane(ev.q), t=lane(ev.t),
+                      valid=lane(ev.valid), ctr=lane(ev.ctr))
+        ent = None if rng_entity is None else rng_entity.reshape(-1)
+        state, info = step(state, lanes, rng, ent)
+        per_event = lambda x: x.reshape((B, K) + x.shape[1:])
+        return state, info._replace(
+            z=per_event(info.z), p=per_event(info.p),
+            lam_hat=per_event(info.lam_hat),
+            features=per_event(info.features))
+    return run
+
+
 def make_step(cfg: EngineConfig, mode: str = "exact", *,
               exact_impl: str = "compact", exact_chunk: int = 256) -> Callable:
     """Build a jit-able engine step: (state, Event, rng) -> (state, StepInfo).
@@ -359,6 +384,7 @@ def make_step(cfg: EngineConfig, mode: str = "exact", *,
     The step also accepts an optional ``rng_entity`` int32 [B] keyword: the
     entity ids fed to the counter-based thinning RNG when ``Event.key`` is a
     local row index rather than the global entity id (sharded callers).
+    ``Event.key`` may be ``[B, K]`` (K profiles per event, ``_per_lane``).
 
     ``exact_impl`` selects the exact-mode round schedule: 'compact' (default,
     segment-compacted O(B + rounds * exact_chunk) work) or 'masked' (the
@@ -368,9 +394,10 @@ def make_step(cfg: EngineConfig, mode: str = "exact", *,
     if mode == "exact":
         if exact_impl not in ("compact", "masked"):
             raise ValueError(f"unknown exact_impl {exact_impl!r}")
-        return functools.partial(_step_exact, cfg, exact_impl, exact_chunk)
+        return _per_lane(functools.partial(_step_exact, cfg, exact_impl,
+                                           exact_chunk))
     if mode == "fast":
-        return functools.partial(_step_fast, cfg)
+        return _per_lane(functools.partial(_step_fast, cfg))
     raise ValueError(f"unknown mode {mode!r}")
 
 

@@ -42,12 +42,18 @@ class ReferenceEngine:
         self.writes = 0
         self.events = 0
 
-    def _uniform(self, key: int, t: float) -> float:
-        bits = np.float32(t).view(np.uint32)
+    def _uniform(self, key: int, t: float, ctr) -> float:
+        c = thinning.counters(np.float32([t]),
+                              None if ctr is None else np.uint32([ctr]))
         return float(thinning.uniform_for_events(
-            self.rng, np.uint32([key]), np.uint32([bits]))[0])
+            self.rng, np.uint32([key]), c)[0])
 
-    def process(self, key: int, q: float, t: float):
+    def process(self, key, q: float, t: float, ctr=None):
+        """One event: ``key`` is an entity id, or the K ids of the profiles
+        the event updates, one after another (a list of results then);
+        ``ctr`` the event's RNG counter (``core.thinning.counters``)."""
+        if np.ndim(key):
+            return [self.process(int(k), q, t, ctr) for k in key]
         cfg, e = self.cfg, self.ents[key]
         self.events += 1
         # decayed state at decision time
@@ -85,7 +91,7 @@ class ReferenceEngine:
             p = min(1.0, cfg.budget / max(lam, 1e-30))
             p = min(max(p, cfg.min_p), 1.0)
 
-        z = self._uniform(key, t) < p
+        z = self._uniform(key, t, ctr) < p
         if z:
             e.agg = agg_now + (1.0 / p) * np.array([1.0, q, q * q])[None, :]
             e.v_f = 1.0 / p + _decay(t - e.last_t, cfg.h) * e.v_f

@@ -346,7 +346,7 @@ class _GroupPlan(NamedTuple):
 
 
 def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
-               *, batch: int = 4096, mode: str = "fast",
+               *, counters=None, batch: int = 4096, mode: str = "fast",
                rng: Optional[jax.Array] = None, collect_info: bool = True,
                donate: bool = True, exact_impl: str = "compact",
                sink=None, sink_group: int = 4, residency=None,
@@ -359,6 +359,17 @@ def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
     flat StepInfo trimmed back to N events (``collect_info=True``) or the
     per-block write counts [n_batches] (``collect_info=False`` — cheapest:
     nothing per-event leaves the device).
+
+    ``keys`` may be ``[N, K]``: each event updates K profiles (rows of one
+    table, such as a bid's bidder and auction), runs as K lanes
+    (``core.engine``) and its StepInfo fields are ``[N, K, ...]``.
+    ``counters``: uint32 [N] per-event RNG counters, such as the stream's
+    event ids; ``None`` keys each draw on its event's time bits (the rule
+    is ``core.thinning.counters``).  With ``[N, K]`` keys a sink's stats
+    time the lane expansion (span ``repro.stream.fanout``, ``SinkStats.
+    fanout_s``) and count the lanes (``fanout_lanes``).  K profiles per
+    event run through the single-scan and sink drivers; the residency
+    drivers take ``[N]`` keys.
 
     ``donate=True`` donates the input state's buffers to the call; do not
     reuse ``state`` afterwards.  (On backends without donation support JAX
@@ -431,12 +442,19 @@ def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
             "groups, which the single-scan path does not have")
     n = int(np.shape(keys)[0])
     pad = (-n) % batch
-    host_blocks = lambda x, fill: np.reshape(
-        np.pad(np.asarray(x), (0, pad), constant_values=fill), (-1, batch))
+
+    def host_blocks(x, fill):
+        x = np.asarray(x)
+        x = np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1),
+                   constant_values=fill)
+        return np.reshape(x, (-1, batch) + x.shape[1:])
     key_h = host_blocks(np.asarray(keys, np.int32), 0)
     q_h = host_blocks(np.asarray(qs, np.float32), 0.0)
     t_h = host_blocks(np.asarray(ts, np.float32), 0.0)
     valid_h = host_blocks(np.ones(n, bool), False)
+    ctr_h = (None if counters is None
+             else host_blocks(np.asarray(counters, np.uint32), 0))
+    fan = key_h.ndim == 3           # [n_blocks, batch, K]
 
     if residency is not None:
         from repro.streaming.residency import (ResidencyMap,
@@ -445,6 +463,9 @@ def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
             raise ValueError(
                 "residency requires a write-behind sink: evicted slots "
                 "rely on the durable store for rehydration")
+        if fan:
+            raise NotImplementedError(
+                "bounded residency takes [N] keys, one profile per event")
         if isinstance(residency, ResidencyMap):
             rmap = residency
         else:
@@ -484,7 +505,8 @@ def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
                 # never from the demote itself (HostL2Cache.demote)
                 sink.demote(asn.evicted)
                 slots = asn.slot.reshape(kseg.shape)
-                ev = Event(key=slots, q=q_h[lo:hi], t=t_h[lo:hi], valid=vm)
+                ev = Event(key=slots, q=q_h[lo:hi], t=t_h[lo:hi], valid=vm,
+                           ctr=None if ctr_h is None else ctr_h[lo:hi])
                 # rng entity ids: the raw key blocks (padding lanes are 0
                 # from the packer; the engine masks invalid lanes itself)
                 ent = kseg
@@ -508,31 +530,43 @@ def run_stream(cfg: EngineConfig, state: ProfileState, keys, qs, ts,
             pipeline_depth=depth)
     elif sink is not None:
         bstep = _sink_step(cfg, mode, collect_info, donate, exact_impl)
+        # the sink's lanes: one per (event, profile), event by event, as
+        # the engine lays them out
+        lane_keys, lane_valid = key_h, valid_h
+        if fan:
+            with sink.overlap.host(), span("repro.stream.fanout",
+                                           sink.stats, "fanout_s"):
+                lane_keys = key_h.reshape(key_h.shape[0], -1)
+                lane_valid = np.repeat(valid_h, key_h.shape[2], axis=1)
+            sink.stats.fanout_lanes += int(lane_valid.sum())
 
         # groups are fed straight from host memory (one h2d per dispatch);
-        # the local engine's gather rows are simply the group's keys
+        # the local engine's gather rows are simply the group's lane keys
         def group_of(lo, hi):
             ev = Event(key=key_h[lo:hi], q=q_h[lo:hi], t=t_h[lo:hi],
-                       valid=valid_h[lo:hi])
-            return ev, key_h[lo:hi].reshape(-1)
+                       valid=valid_h[lo:hi],
+                       ctr=None if ctr_h is None else ctr_h[lo:hi])
+            return ev, lane_keys[lo:hi].reshape(-1)
 
         state, info = _drive_with_sink(
             bstep, state, key_h.shape[0], max(1, int(sink_group)), group_of,
-            rng, sink, sink_keys=key_h, valid_host=valid_h,
+            rng, sink, sink_keys=lane_keys, valid_host=lane_valid,
             collect_info=collect_info, pipeline_depth=depth)
     else:
         events = Event(key=jnp.asarray(key_h), q=jnp.asarray(q_h),
-                       t=jnp.asarray(t_h), valid=jnp.asarray(valid_h))
+                       t=jnp.asarray(t_h), valid=jnp.asarray(valid_h),
+                       ctr=None if ctr_h is None else jnp.asarray(ctr_h))
         state, info = _block_runner(cfg, mode, collect_info, donate,
                                     exact_impl)(state, events, rng)
     if not collect_info:
         return state, info
     if n == 0:                  # degenerate but valid: nothing to trim
         F = 4 * len(cfg.taus)
+        lanes = (0,) + key_h.shape[2:]
         return state, StepInfo(
-            z=jnp.zeros((0,), bool), p=jnp.zeros((0,), jnp.float32),
-            lam_hat=jnp.zeros((0,), jnp.float32),
-            features=jnp.zeros((0, F), jnp.float32),
+            z=jnp.zeros(lanes, bool), p=jnp.zeros(lanes, jnp.float32),
+            lam_hat=jnp.zeros(lanes, jnp.float32),
+            features=jnp.zeros(lanes + (F,), jnp.float32),
             writes=jnp.zeros((), jnp.int32))
     flat = lambda x: jnp.reshape(x, (-1,) + x.shape[2:])[:n]
     return state, StepInfo(

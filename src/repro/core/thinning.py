@@ -56,25 +56,36 @@ def fixed_rate_inclusion(shape, rate: float | jax.Array,
 
 def bernoulli_mask(rng: jax.Array, key_ids: jax.Array, seq_ids: jax.Array,
                    p: jax.Array) -> jax.Array:
-    """Reproducible, order-independent thinning decisions.
-
-    Uniforms are derived counter-style from (entity, per-entity sequence
-    number) so the decision for a given event is independent of batch
-    composition, shard placement and replay order — required for the
-    exact/fast engine modes to agree and for cross-shard determinism.
-    """
+    """Reproducible, order-independent thinning decisions: ``u < p`` with
+    each event's uniform keyed on (entity, counter) by the counter rule of
+    ``counters`` below."""
     u = uniform_for_events(rng, key_ids, seq_ids)
     return u < p
 
 
 def time_bits(t: jax.Array) -> jax.Array:
-    """Per-event RNG counter: the float32 bit pattern of the timestamp.
-
-    The single definition shared by the engine and the per-event worker —
-    both must feed identical counters to ``uniform_for_events`` for the
-    persistence byte-parity contract to hold.
-    """
+    """The float32 bit pattern of each timestamp: the default counter."""
     return jax.lax.bitcast_convert_type(t.astype(jnp.float32), jnp.uint32)
+
+
+def counters(t: jax.Array, ctr=None) -> jax.Array:
+    """Per-event RNG counters, by the one rule the engine, the per-event
+    oracle (``core.reference``) and the worker (``streaming.worker``) share.
+
+    Each event's thinning uniform is keyed on (entity, counter), so the
+    decision for a given event is independent of batch composition, shard
+    placement and replay order: the exact and fast modes agree, and a
+    worker stores the bytes the engine's sink stores.  The counter is the
+    stream's own per-event id ``ctr`` (uint32) where the stream supplies
+    one, and otherwise ``time_bits(t)``.  Two events of one entity with
+    equal counters draw the same uniform and are written together or not
+    at all, which breaks the independence of the Horvitz-Thompson draws:
+    a stream whose events can share a timestamp on one entity (event times
+    of millisecond resolution, several events a millisecond) has to supply
+    ``ctr``.
+    """
+    return time_bits(t) if ctr is None else jnp.asarray(ctr).astype(
+        jnp.uint32)
 
 
 def uniform_for_events(rng: jax.Array, key_ids: jax.Array,

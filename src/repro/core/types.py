@@ -10,7 +10,7 @@ count/sum/sumsq columns), never from an auxiliary in-memory plane.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -23,12 +23,22 @@ NUM_AGG_COLS = 3
 
 
 class Event(NamedTuple):
-    """A micro-batch of events (vectors of length B)."""
+    """A micro-batch of B events.
 
-    key: jax.Array    # int32 [B] entity index
+    An event updates the profiles of K entities (K = 1 where ``key`` is
+    ``[B]``): the engine runs one lane per (event, profile) pair, event by
+    event, and returns each lane's outputs as ``[B, K]``.
+    """
+
+    key: jax.Array    # int32 [B] or [B, K] entity (state row) index
     q: jax.Array      # float32 [B] quantitative mark (e.g. amount)
-    t: jax.Array      # float32 [B] event timestamp (seconds)
+    # float32 [B] event time in seconds; float32 keeps distinct
+    # milliseconds distinct below 2**14 s (its step there is 2**-10 s)
+    t: jax.Array
     valid: jax.Array  # bool [B] padding mask
+    # uint32 [B] per-event RNG counter, or None for ``time_bits(t)``: the
+    # rule is ``core.thinning.counters``
+    ctr: Optional[jax.Array] = None
 
 
 class ProfileState(NamedTuple):
@@ -115,7 +125,8 @@ class EngineConfig:
 
 
 class StepInfo(NamedTuple):
-    """Per-event observability emitted by one engine step."""
+    """Per-event observability emitted by one engine step; with K profiles
+    per event each per-event field has a K axis after B."""
 
     z: jax.Array         # bool [B] persisted?
     p: jax.Array         # f32 [B] inclusion probability used

@@ -123,6 +123,11 @@ class SinkStats:
     dispatch_s: float = 0.0
     outputs_s: float = 0.0
     outputs_d2h_bytes: int = 0
+    # repro.stream.fanout: the driver's expansion of events that update
+    # K profiles each into lanes, one per (event, profile), and the lanes
+    # (K per event)
+    fanout_s: float = 0.0
+    fanout_lanes: int = 0
     # repro.sink.rows_d2h: the dispatcher's copy of each block's gathered
     # post-update rows off the device, and its bytes
     rows_d2h_s: float = 0.0

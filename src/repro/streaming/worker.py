@@ -18,7 +18,8 @@ Two design points make that parity exact rather than approximate:
 
 * the worker holds no private decision math — steps (2)-(4) route through
   the same fused kernel as the vectorized engine (``ops.thinning_rmw`` on a
-  single-event batch, with counter-based uniforms keyed on (entity, time)),
+  single-event batch, with counter-based uniforms keyed on (entity,
+  counter) by the rule of ``core.thinning.counters``),
   so decisions AND updated row values are bit-identical to the engine's
   (the kernel's reference path is compilation-context-invariant — see
   ``kernels/detmath.py``);
@@ -58,8 +59,7 @@ _FULL_STREAM = ("full", "unfiltered")
 # plain ``ops.thinning_rmw`` jit entry — folding it into a bigger per-event
 # program would compile the kernel in yet another context, which is exactly
 # what the byte-parity contract must avoid (see kernels/detmath.py).
-_uniform_jit = jax.jit(lambda rng, ent, t: thinning.uniform_for_events(
-    rng, ent, thinning.time_bits(t)))
+_uniform_jit = jax.jit(thinning.uniform_for_events)
 
 
 @functools.lru_cache(maxsize=None)
@@ -75,9 +75,9 @@ def _event_step(cfg: EngineConfig):
               min_p=cfg.min_p)
     ones = jnp.ones((1,), jnp.float32)
 
-    def step(rng, ent, last_t, v_f, agg, q, t, v_full, last_t_full):
+    def step(rng, ent, ctr, last_t, v_f, agg, q, t, v_full, last_t_full):
         t1 = t[None]
-        u = _uniform_jit(rng, ent[None], t1)
+        u = _uniform_jit(rng, ent[None], ctr[None])
         return ops.thinning_rmw(
             taus, last_t[None], v_f[None], agg.reshape(1, -1), q[None],
             t1, u, ones, v_full[None], last_t_full[None], **kw)
@@ -107,7 +107,7 @@ class FeatureWorker:
     """One partition worker: KV store + persistence-path control.
 
     ``rng`` is the thinning RNG root (a jax PRNG key).  Decisions are
-    counter-based on (entity id, event-time bits) — reproducible and
+    counter-based on (entity id, event counter) — reproducible and
     order/batching-invariant, and identical to the vectorized engine's when
     the same root key is used (which is what the parity tests do).
     """
@@ -130,8 +130,12 @@ class FeatureWorker:
         """-inf -> kernel freshness sentinel (finite, VPU-safe)."""
         return np.float32(x) if math.isfinite(x) else _FRESH_SENTINEL
 
-    def process(self, key: int, q: float, t: float) -> dict:
+    def process(self, key, q: float, t: float, ctr=None):
         """One event through the worker loop.  Returns observability dict.
+
+        ``key`` is an entity id, or the K ids of the profiles the event
+        updates, one after another (a list of dicts then); ``ctr`` is the
+        event's RNG counter (``core.thinning.counters``).
 
         ``latency_s`` in the result (and ``metrics.latencies_s``) is the
         worker-model per-event latency: real SerDe seconds + modeled
@@ -139,6 +143,8 @@ class FeatureWorker:
         of the oracle implementation (dominated by per-event jax dispatch)
         and is reported separately.
         """
+        if np.ndim(key):
+            return [self.process(int(k), q, t, ctr) for k in key]
         serde, store = self.serde, self.store
         t0 = time.perf_counter()
         io0 = store.counters.modeled_io_s
@@ -159,6 +165,8 @@ class FeatureWorker:
         # on a single-event batch (no private decision math in this class).
         (nlt, nvf, nagg, z_, p_, feats, lam_, nvfull, nltf) = self._step(
             self.rng, jnp.asarray(int(key), jnp.uint32),
+            thinning.counters(jnp.float32(t),
+                              None if ctr is None else np.uint32(ctr)),
             jnp.asarray(self._fin(last_t)), jnp.asarray(np.float32(v_f)),
             jnp.asarray(agg, jnp.float32), jnp.asarray(np.float32(q)),
             jnp.asarray(np.float32(t)), jnp.asarray(np.float32(v_full)),

@@ -324,7 +324,7 @@ def _dense_fast_step(cfg, state, ev, rng):
     num_e = state.num_entities
     safe_key = jnp.where(ev.valid, ev.key, 0)
     u = thinning.uniform_for_events(rng, safe_key,
-                                    core_engine._seq_bits(ev.t))
+                                    thinning.counters(ev.t, ev.ctr))
     (_, _, _, z, p, feats, lam, _, _) = core_engine._fused_rmw(
         cfg, taus, state, safe_key, ev.q, ev.t, u, ev.valid)
 
